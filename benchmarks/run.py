@@ -27,6 +27,8 @@ import sys
 import time
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 BENCHES = [
     "bench_trace_analysis",
     "bench_fig6_utilization",
@@ -102,6 +104,7 @@ def record_run(path: str, bench: str, rows, *, commit: str,
 
 
 def main() -> None:
+    enable_compile_cache()
     full = os.environ.get("REPRO_FULL", "0") == "1"
     args = sys.argv[1:]
     write_json = "--json" in args

@@ -12,11 +12,13 @@ import sys
 import time
 
 from repro.api import Experiment, list_policies
+from repro.compile_cache import enable_compile_cache
 from repro.core import SimConfig
 from repro.traces import generate_calibrated
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--out", default=None)

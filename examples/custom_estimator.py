@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api import Experiment, register_estimator
+from repro.compile_cache import enable_compile_cache
 from repro.core import SimConfig
 from repro.estimators import EstimatorState, zeros_state
 from repro.traces import generate_calibrated
@@ -39,6 +40,7 @@ class PeakHoldEstimator:
 
 
 def main():
+    enable_compile_cache()
     cfg = SimConfig(n_nodes=100, n_slots=32, arrivals_per_slot=256,
                     retry_capacity=64, reclamation=True, reclaim_pool=256)
     ts = generate_calibrated(0, cfg.n_nodes, cfg.n_slots, offered_load=1.6)

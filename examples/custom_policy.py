@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api import Experiment, admission, register_policy
+from repro.compile_cache import enable_compile_cache
 from repro.core import SimConfig
 from repro.traces import generate_calibrated
 
@@ -36,6 +37,7 @@ class RandomFitPolicy:
 
 
 def main():
+    enable_compile_cache()
     cfg = SimConfig(n_nodes=200, n_slots=64, arrivals_per_slot=1024,
                     retry_capacity=256)
     ts = generate_calibrated(0, cfg.n_nodes, cfg.n_slots, offered_load=1.6)

@@ -8,11 +8,13 @@ LeastFit's QoS.
   PYTHONPATH=src python examples/quickstart.py
 """
 from repro.api import Experiment
+from repro.compile_cache import enable_compile_cache
 from repro.core import SimConfig
 from repro.traces import generate_calibrated
 
 
 def main():
+    enable_compile_cache()
     cfg = SimConfig(n_nodes=200, n_slots=96, arrivals_per_slot=1024,
                     retry_capacity=256)
     ts = generate_calibrated(0, cfg.n_nodes, cfg.n_slots, offered_load=1.6)

@@ -5,7 +5,12 @@ requests over-declare max_tokens (like Google-trace users over-request).
 Flex admission packs ~2-3x more concurrent requests at the same QoS.
 
   PYTHONPATH=src python examples/serve_flex.py
+
+Each policy runs in its own child process; this parent never imports
+JAX, so a child can hold the accelerator.  The children inherit the
+environment, ``JAX_COMPILATION_CACHE_DIR`` included.
 """
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -20,7 +25,7 @@ def main():
             [sys.executable, "-m", "repro.launch.serve",
              "--policy", policy, "--requests", "48", "--steps", "100",
              "--budget", "384", "--slots", "12"],
-            env={"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin"},
+            env={**os.environ, "PYTHONPATH": str(ROOT / "src")},
             check=True)
 
 

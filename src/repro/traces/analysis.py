@@ -220,7 +220,9 @@ def summarize(ts: TaskSet, result: SimResult, qos_target: float) -> Dict[str, fl
     ``overprovisioning``, ``zombie_nodes``) are included when the run
     recorded per-node series and SKIPPED WITH A WARNING otherwise —
     callers need not know about ``SimConfig(record_node_usage=True)`` to
-    get the cluster-level summary.
+    get the cluster-level summary.  Guard keys (``guard_report``) are
+    included only when the run was guarded; the guard is off by default,
+    so an unguarded run omits them without a warning.
     """
     m = result.metrics
     admitted = result.placement >= 0
@@ -250,10 +252,4 @@ def summarize(ts: TaskSet, result: SimResult, qos_target: float) -> Dict[str, fl
             stacklevel=2)
     if m.guard_tripped.size:
         out.update(guard_report(result))
-    else:
-        warnings.warn(
-            "summarize: skipping guard keys (guard_report) — the run was "
-            "unguarded; pass SimConfig(guard=GuardConfig(...)) to include "
-            "them",
-            stacklevel=2)
     return out

@@ -16,7 +16,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -54,10 +53,10 @@ def compressed_allreduce(grad: jnp.ndarray, mesh: Mesh,
         sall = jax.lax.all_gather(s2, axis)
         return (qall.astype(jnp.float32) * sall[:, None]).reshape(-1)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=P(),      # replicated input
-                   out_specs=P(),     # replicated output
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=P(),      # replicated input
+                       out_specs=P(),     # replicated output
+                       check_vma=False)
     return fn(grad)
 
 

@@ -41,7 +41,7 @@ def _assert_matches(N, tile, scale, **kw):
             assert int(i_k) == -1
 
 
-@pytest.mark.parametrize("N,tile", [(256, 64), (1024, 256), (512, 512)])
+@pytest.mark.parametrize("N,tile", [(256, 128), (1024, 256), (512, 512)])
 @pytest.mark.parametrize("scale", [0.2, 0.8, 3.0])
 def test_matches_ref(N, tile, scale):
     _assert_matches(N, tile, scale)
@@ -52,11 +52,11 @@ def test_matches_ref(N, tile, scale):
 def test_non_tile_multiple_matches_ref(N, scale):
     # N not a multiple of the tile: the wrapper zero-pads the node table
     # and the kernel masks the tail rows (no reference-path fallback).
-    _assert_matches(N, 64, scale)
+    _assert_matches(N, 128, scale)
     _assert_matches(N, 512, scale)
 
 
-@pytest.mark.parametrize("N,tile", [(128, 64), (513, 512)])
+@pytest.mark.parametrize("N,tile", [(256, 128), (513, 512)])
 def test_all_infeasible_returns_minus_one(N, tile):
     # N=513/tile=512 covers the padding trap: zero-padded tail rows have
     # zero load and WOULD be feasible if the in-kernel row mask failed.
@@ -87,7 +87,7 @@ def test_batch_matches_batch_ref(N, Q):
     ones = jnp.ones((Q,))
     i_k, _, f_k = flex_pick_node_batch(est, res, src, r, pen, w_load=ones,
                                        w_src=ones * 0.25, cap=ones,
-                                       tile=64, interpret=True)
+                                       tile=128, interpret=True)
     i_r, _, f_r = pick_node_batch_ref(est, res, src, r, pen, ones,
                                       ones * 0.25, cap=ones)
     assert (jnp.asarray(i_k) == jnp.asarray(i_r)).all()
@@ -103,11 +103,11 @@ def test_batch_rows_match_per_task_kernel(scale):
     est, res, src, r = _rand_batch(N, Q, scale)
     pen = 1.3
     i_b, s_b, f_b = flex_pick_node_batch(est, res, src, r, pen, w_load=1.0,
-                                         w_src=0.25, cap=1.0, tile=64,
+                                         w_src=0.25, cap=1.0, tile=128,
                                          interpret=True)
     for q in range(Q):
         i_1, s_1, f_1 = flex_pick_node(est, res, src[q], r[q], pen,
-                                       tile=64, interpret=True)
+                                       tile=128, interpret=True)
         assert int(i_1) == int(i_b[q])
         assert bool(f_1) == bool(f_b[q])
         if bool(f_1):
@@ -125,12 +125,12 @@ def test_batch_per_task_scalars():
     w_load = jnp.where(jnp.arange(Q) % 2 == 0, 1.0, -1.0)  # incl. best-fit
     w_src = 0.25 * jax.random.uniform(ks[3], (Q,))
     i_b, _, f_b = flex_pick_node_batch(est, res, src, r, pen, w_load=w_load,
-                                       w_src=w_src, cap=cap, tile=64,
+                                       w_src=w_src, cap=cap, tile=128,
                                        interpret=True)
     for q in range(Q):
         i_1, _, f_1 = flex_pick_node(est, res, src[q], r[q], pen[q],
                                      w_load=w_load[q], w_src=w_src[q],
-                                     cap=cap[q], tile=64, interpret=True)
+                                     cap=cap[q], tile=128, interpret=True)
         assert int(i_1) == int(i_b[q])
         assert bool(f_1) == bool(f_b[q])
 
@@ -166,7 +166,7 @@ def test_topk_matches_topk_ref(N, k):
     ones = jnp.ones((Q,))
     i_k, s_k, f_k = flex_pick_node_batch_topk(est, res, src, r, pen,
                                               w_load=ones, w_src=ones * 0.25,
-                                              cap=ones, k=k, tile=64,
+                                              cap=ones, k=k, tile=128,
                                               interpret=True)
     i_r, s_r, f_r = pick_node_batch_topk_ref(est, res, src, r, pen, ones,
                                              ones * 0.25, cap=ones, k=k)
@@ -184,7 +184,7 @@ def test_topk_matches_topk_ref(N, k):
 def test_topk_k1_reduces_to_argmax_path():
     # K=1 must BE the existing batched argmax: same winner, bit-identical
     # best score (identical float expressions through the same kernel).
-    for N, tile in [(5, 512), (100, 64), (513, 512), (1024, 256)]:
+    for N, tile in [(5, 512), (300, 128), (513, 512), (1024, 256)]:
         Q = 7
         est, res, src, r = _rand_batch(N, Q, 0.8, seed=N)
         i_1, s_1, f_1 = flex_pick_node_batch(est, res, src, r, 1.3,
@@ -210,12 +210,12 @@ def test_topk_column0_is_argmax_for_any_k():
     N, Q = 513, 8
     est, res, src, r = _rand_batch(N, Q, 0.8)
     i_1, _, _ = flex_pick_node_batch(est, res, src, r, 1.3, w_load=1.0,
-                                     w_src=0.25, cap=1.0, tile=64,
+                                     w_src=0.25, cap=1.0, tile=128,
                                      interpret=True)
     for k in (2, 8, 16):
         i_t, s_t, _ = flex_pick_node_batch_topk(est, res, src, r, 1.3,
                                                 w_load=1.0, w_src=0.25,
-                                                cap=1.0, k=k, tile=64,
+                                                cap=1.0, k=k, tile=128,
                                                 interpret=True)
         assert (jnp.asarray(i_t[:, 0]) == jnp.asarray(i_1)).all()
         # sorted, and ties (if any) break toward the lower node index
@@ -226,7 +226,7 @@ def test_topk_ties_break_toward_lowest_index():
     # All-equal node state: every feasible node scores identically, so
     # the candidate list must be exactly [0, 1, 2, ...] on both paths
     # (argmax first-occurrence, applied k-deep).
-    N, Q, k = 40, 5, 6
+    N, Q, k = 300, 5, 6
     est = jnp.zeros((N, 2))
     res = jnp.zeros((N, 2))
     src = jnp.zeros((Q, N))
@@ -234,7 +234,7 @@ def test_topk_ties_break_toward_lowest_index():
     ones = jnp.ones((Q,))
     i_k, _, _ = flex_pick_node_batch_topk(est, res, src, r, ones,
                                           w_load=ones, w_src=ones * 0.25,
-                                          cap=ones, k=k, tile=16,
+                                          cap=ones, k=k, tile=128,
                                           interpret=True)
     assert (jnp.asarray(i_k)
             == jnp.broadcast_to(jnp.arange(k), (Q, k))).all()
@@ -277,7 +277,7 @@ def test_topk_per_task_scalars():
     w_src = 0.25 * jax.random.uniform(ks[3], (Q,))
     i_k, _, f_k = flex_pick_node_batch_topk(est, res, src, r, pen,
                                             w_load=w_load, w_src=w_src,
-                                            cap=cap, k=k, tile=64,
+                                            cap=cap, k=k, tile=128,
                                             interpret=True)
     i_r, _, f_r = pick_node_batch_topk_ref(est, res, src, r, pen, w_load,
                                            w_src, cap=cap, k=k)
@@ -293,7 +293,7 @@ def test_cap_parameter_matches_ref(N):
     r = jnp.asarray([0.08, 0.1])
     for cap in (0.7, 0.9):
         i_k, _, f_k = flex_pick_node(est, res, src, r, 1.2, cap=cap,
-                                     tile=64, interpret=True)
+                                     tile=128, interpret=True)
         i_r, _, f_r = pick_node_ref(est, res, src, r, 1.2, 1.0, 0.25,
                                     cap=cap)
         assert bool(f_k) == bool(f_r)
