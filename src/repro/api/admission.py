@@ -246,22 +246,27 @@ def admit_queue(policy, node: NodeState, requests, srcs, priorities,
         sequential scan).  Policies without the hook silently fall back
         to the sequential scan.
 
+    Both shapes run under the named scope ``flex.admission``, so a device
+    trace shows the admission core's ops under that path whatever pass
+    called it (docs/kernels.md, "Named scopes").
+
     Returns (NodeState, placements (Q,) — node idx or -1).
     """
-    if batch_mode and getattr(policy, "kernel_inputs", None) is not None:
-        return admit_queue_wavefront(policy, node, requests, srcs,
-                                     priorities, valid, penalty, params,
-                                     interpret=interpret, topk=topk,
-                                     dedup_buckets=dedup_buckets,
-                                     tie_margin=tie_margin)
+    with jax.named_scope("flex.admission"):
+        if batch_mode and getattr(policy, "kernel_inputs", None) is not None:
+            return admit_queue_wavefront(policy, node, requests, srcs,
+                                         priorities, valid, penalty, params,
+                                         interpret=interpret, topk=topk,
+                                         dedup_buckets=dedup_buckets,
+                                         tie_margin=tie_margin)
 
-    def step(ns, xs):
-        r, src, prio, ok = xs
-        ctx = PolicyContext(node=ns, penalty=penalty, params=params)
-        return admit_one(policy, ctx, TaskView(r, src, prio), ok,
-                         use_kernel=use_kernel, interpret=interpret)
+        def step(ns, xs):
+            r, src, prio, ok = xs
+            ctx = PolicyContext(node=ns, penalty=penalty, params=params)
+            return admit_one(policy, ctx, TaskView(r, src, prio), ok,
+                             use_kernel=use_kernel, interpret=interpret)
 
-    return jax.lax.scan(step, node, (requests, srcs, priorities, valid))
+        return jax.lax.scan(step, node, (requests, srcs, priorities, valid))
 
 
 def make_queue_admitter(policy, params: FlexParams, *,

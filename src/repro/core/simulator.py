@@ -46,6 +46,10 @@ Per-slot pipeline (semantics match Kubernetes + Alg. 3):
      margin) via the ``reclaim`` policy — through the same
      ``admit_queue_wavefront`` path as primary admission
 
+Each pass runs under a ``jax.named_scope`` (``flex.slot.aggregates``,
+``flex.slot.admit``, ...), so a device trace shows it as the ops' path
+(docs/kernels.md, "Named scopes").
+
 ``faults=None`` with ``retry_backoff=0`` (the defaults) compiles the exact
 pre-fault program — bit-identical decisions (tests/test_faults.py asserts
 the identity schedule matches it too).
@@ -303,167 +307,177 @@ def simulate_core(
         # --- 0. fault + degradation evictions ------------------------------
         # Before the aggregates, so freed capacity is admissible this slot.
         if faults_on:
-            resident = (placement_in >= 0) & (slot <= admit_in + dur)
-            on_down = resident & ~slot_up[jnp.clip(placement_in, 0,
-                                                   n_nodes - 1)]
-            n_fault_ev = (carry["n_fault_evicted"]
-                          + jnp.sum(on_down.astype(jnp.int32)))
-            evict_mask = on_down
-            degrade_mask = jnp.zeros((T,), bool)
-            if degrade_on:
-                pressure = _degrade.under_pressure(carry["qos_win"], thr)
-                reclaimed = (carry["reclaimed"] if cfg.reclamation
-                             else jnp.zeros((T,), bool))
-                rank = _degrade.victim_rank(ts.priority, reclaimed,
-                                            fcfg.degrade_spare_production)
-                degrade_mask = _degrade.select_victims(
-                    resident & ~on_down & pressure, rank, admit_in,
-                    n_slots, fcfg.degrade_evict)
-                evict_mask = on_down | degrade_mask
-                n_degrade_ev = (carry["n_degrade_evicted"]
-                                + jnp.sum(degrade_mask.astype(jnp.int32)))
-            forced_retry = on_down
-            if migration_on:
-                # Drain sources: fault-announced warning windows, plus —
-                # when overload_threshold > 0 — nodes whose previous-slot
-                # dominant estimate marks them as hotspots.  Down nodes are
-                # not sources (their residents were just crash-evicted).
-                drain_src = slot_drain
-                if mcfg.overload_threshold > 0:
-                    drain_src = drain_src | (
-                        jnp.max(carry["est"].est, axis=-1)
-                        > mcfg.overload_threshold)
-                drain_src = drain_src & slot_up
-                want = (resident & ~evict_mask
-                        & drain_src[jnp.clip(placement_in, 0, n_nodes - 1)])
-                # Revalidate carried pool entries (node recovered / task
-                # finished / crash-evicted this slot -> silently leave),
-                # then merge newly-draining residents in, valid-first.
-                pool_prev = carry["mig_pool"]
-                ppqi = jnp.maximum(pool_prev, 0)
-                pool_keep = (pool_prev >= 0) & want[ppqi]
-                in_pool = jnp.zeros((T,), jnp.int32).at[ppqi].max(
-                    pool_keep.astype(jnp.int32)).astype(bool)
-                merged_m = jnp.concatenate([
-                    jnp.where(pool_keep, pool_prev, -1),
-                    _compact_ids(want & ~in_pool, mcfg.pool_size)])
-                merged_m = merged_m[jnp.argsort(merged_m < 0, stable=True)]
-                mig_pool = merged_m[:mcfg.pool_size]
-                # Pool OVERFLOW cannot be moved before the fault lands:
-                # fall back to the evict-to-retry path (PR 8 semantics).
-                mig_over = merged_m[mcfg.pool_size:]
-                over_mask = jnp.zeros((T,), jnp.int32).at[
-                    jnp.maximum(mig_over, 0)].max(
-                        (mig_over >= 0).astype(jnp.int32)).astype(bool)
-                n_mig_failed = (carry["n_migration_failed"]
-                                + jnp.sum((mig_over >= 0).astype(jnp.int32)))
-                evict_mask = evict_mask | over_mask
-                forced_retry = forced_retry | over_mask
-            placement_in = jnp.where(evict_mask, -1, placement_in)
-            admit_in = jnp.where(evict_mask, -1, admit_in)
-            # Evictions routed through the retry queue consume an attempt
-            # and arm the exponential backoff (generalizing max_retries);
-            # pool-shed victims wait on the reclaim pass instead.
-            retry_evict = forced_retry if shed_to_pool else evict_mask
-            attempts = attempts + retry_evict.astype(jnp.int32)
-            ev_delay = _inject.backoff_delay(
-                attempts, cfg.retry_backoff, cfg.retry_backoff_cap)
-            if jitter_on:
-                ev_delay = ev_delay + jit_tab
-            next_try = jnp.where(retry_evict, slot + 1 + ev_delay, next_try)
-            evict_requeue = retry_evict & (attempts <= cfg.max_retries)
+            with jax.named_scope("flex.slot.faults"):
+                resident = (placement_in >= 0) & (slot <= admit_in + dur)
+                on_down = resident & ~slot_up[jnp.clip(placement_in, 0,
+                                                       n_nodes - 1)]
+                n_fault_ev = (carry["n_fault_evicted"]
+                              + jnp.sum(on_down.astype(jnp.int32)))
+                evict_mask = on_down
+                degrade_mask = jnp.zeros((T,), bool)
+                if degrade_on:
+                    pressure = _degrade.under_pressure(carry["qos_win"], thr)
+                    reclaimed = (carry["reclaimed"] if cfg.reclamation
+                                 else jnp.zeros((T,), bool))
+                    rank = _degrade.victim_rank(ts.priority, reclaimed,
+                                                fcfg.degrade_spare_production)
+                    degrade_mask = _degrade.select_victims(
+                        resident & ~on_down & pressure, rank, admit_in,
+                        n_slots, fcfg.degrade_evict)
+                    evict_mask = on_down | degrade_mask
+                    n_degrade_ev = (carry["n_degrade_evicted"]
+                                    + jnp.sum(degrade_mask.astype(jnp.int32)))
+                forced_retry = on_down
+                if migration_on:
+                    # Drain sources: fault-announced warning windows, plus —
+                    # when overload_threshold > 0 — nodes whose previous-slot
+                    # dominant estimate marks them as hotspots.  Down nodes are
+                    # not sources (their residents were just crash-evicted).
+                    drain_src = slot_drain
+                    if mcfg.overload_threshold > 0:
+                        drain_src = drain_src | (
+                            jnp.max(carry["est"].est, axis=-1)
+                            > mcfg.overload_threshold)
+                    drain_src = drain_src & slot_up
+                    want = (resident & ~evict_mask
+                            & drain_src[jnp.clip(placement_in, 0,
+                                                 n_nodes - 1)])
+                    # Revalidate carried pool entries (node recovered / task
+                    # finished / crash-evicted this slot -> silently leave),
+                    # then merge newly-draining residents in, valid-first.
+                    pool_prev = carry["mig_pool"]
+                    ppqi = jnp.maximum(pool_prev, 0)
+                    pool_keep = (pool_prev >= 0) & want[ppqi]
+                    in_pool = jnp.zeros((T,), jnp.int32).at[ppqi].max(
+                        pool_keep.astype(jnp.int32)).astype(bool)
+                    merged_m = jnp.concatenate([
+                        jnp.where(pool_keep, pool_prev, -1),
+                        _compact_ids(want & ~in_pool, mcfg.pool_size)])
+                    merged_m = merged_m[jnp.argsort(merged_m < 0, stable=True)]
+                    mig_pool = merged_m[:mcfg.pool_size]
+                    # Pool OVERFLOW cannot be moved before the fault lands:
+                    # fall back to the evict-to-retry path (PR 8 semantics).
+                    mig_over = merged_m[mcfg.pool_size:]
+                    over_mask = jnp.zeros((T,), jnp.int32).at[
+                        jnp.maximum(mig_over, 0)].max(
+                            (mig_over >= 0).astype(jnp.int32)).astype(bool)
+                    n_mig_failed = (
+                        carry["n_migration_failed"]
+                        + jnp.sum((mig_over >= 0).astype(jnp.int32)))
+                    evict_mask = evict_mask | over_mask
+                    forced_retry = forced_retry | over_mask
+                placement_in = jnp.where(evict_mask, -1, placement_in)
+                admit_in = jnp.where(evict_mask, -1, admit_in)
+                # Evictions routed through the retry queue consume an attempt
+                # and arm the exponential backoff (generalizing max_retries);
+                # pool-shed victims wait on the reclaim pass instead.
+                retry_evict = forced_retry if shed_to_pool else evict_mask
+                attempts = attempts + retry_evict.astype(jnp.int32)
+                ev_delay = _inject.backoff_delay(
+                    attempts, cfg.retry_backoff, cfg.retry_backoff_cap)
+                if jitter_on:
+                    ev_delay = ev_delay + jit_tab
+                next_try = jnp.where(retry_evict, slot + 1 + ev_delay,
+                                     next_try)
+                evict_requeue = retry_evict & (attempts <= cfg.max_retries)
             evict_exhausted = retry_evict & (attempts > cfg.max_retries)
 
         # --- 1. node aggregates for the active set -----------------------
-        active, seg, requested, n_tasks, src_count = _node_aggregates(
-            ts, placement_in, admit_in, slot, n_nodes,
-            dur if migration_on else None)
+        with jax.named_scope("flex.slot.aggregates"):
+            active, seg, requested, n_tasks, src_count = _node_aggregates(
+                ts, placement_in, admit_in, slot, n_nodes,
+                dur if migration_on else None)
 
         # --- 2. demand process: AR(1) around the task mean ----------------
-        k_slot = jax.random.fold_in(key, slot)
-        white = jax.random.normal(k_slot, (T,), jnp.float32)
-        noise = ts.ar_rho * carry["noise"] + jnp.sqrt(
-            jnp.maximum(1.0 - ts.ar_rho ** 2, 0.0)) * white
-        demand = jnp.clip(
-            ts.mean_usage + ts.std_usage * noise[:, None],
-            0.0, ts.peak_usage) * demand_scale
-        if faults_on:
-            # black-swan surge: resident tasks on surging nodes spike
-            task_mult = jnp.where(active, slot_mult[seg], 1.0)
-            demand = demand * task_mult[:, None]
-        demand = jnp.minimum(demand, 1.0)  # a task never exceeds one node
+        with jax.named_scope("flex.slot.demand"):
+            k_slot = jax.random.fold_in(key, slot)
+            white = jax.random.normal(k_slot, (T,), jnp.float32)
+            noise = ts.ar_rho * carry["noise"] + jnp.sqrt(
+                jnp.maximum(1.0 - ts.ar_rho ** 2, 0.0)) * white
+            demand = jnp.clip(
+                ts.mean_usage + ts.std_usage * noise[:, None],
+                0.0, ts.peak_usage) * demand_scale
+            if faults_on:
+                # black-swan surge: resident tasks on surging nodes spike
+                task_mult = jnp.where(active, slot_mult[seg], 1.0)
+                demand = demand * task_mult[:, None]
+            demand = jnp.minimum(demand, 1.0)  # a task never exceeds one node
 
         # --- 3. allocation + QoS ------------------------------------------
-        wfs_cap = (jnp.where(slot_up, slot_cap, 0.0) if faults_on else 1.0)
-        alloc, node_usage = allocation.wfs_allocate(
-            demand, ts.request, placement_in, active, n_nodes,
-            capacity=wfs_cap, iters=cfg.wfs_iters)
-        q_task = qos.task_qos(alloc, demand, ts.request)
-        if faults_on:
-            # an eviction IS a broken SLO: victims count as active
-            # violators in their eviction slot
-            q_cluster = qos.cluster_qos(q_task & ~evict_mask,
-                                        active | evict_mask)
-        else:
-            q_cluster = qos.cluster_qos(q_task, active)
+        with jax.named_scope("flex.slot.allocate"):
+            wfs_cap = (jnp.where(slot_up, slot_cap, 0.0) if faults_on else 1.0)
+            alloc, node_usage = allocation.wfs_allocate(
+                demand, ts.request, placement_in, active, n_nodes,
+                capacity=wfs_cap, iters=cfg.wfs_iters)
+            q_task = qos.task_qos(alloc, demand, ts.request)
+            if faults_on:
+                # an eviction IS a broken SLO: victims count as active
+                # violators in their eviction slot
+                q_cluster = qos.cluster_qos(q_task & ~evict_mask,
+                                            active | evict_mask)
+            else:
+                q_cluster = qos.cluster_qos(q_task, active)
 
-        qos_ok = carry["qos_ok"] + (q_task & active).astype(jnp.int32)
-        active_cnt = carry["active_cnt"] + active.astype(jnp.int32)
+            qos_ok = carry["qos_ok"] + (q_task & active).astype(jnp.int32)
+            active_cnt = carry["active_cnt"] + active.astype(jnp.int32)
 
         # --- 4. penalty controller ----------------------------------------
-        ctrl = ctrl_impl.update(carry["ctrl"], q_cluster, params)
+        with jax.named_scope("flex.slot.penalty"):
+            ctrl = ctrl_impl.update(carry["ctrl"], q_cluster, params)
 
-        # --- 4.5 estimator-drift watchdog + breaker ------------------------
-        # The estimate refreshed LAST slot is what admission judged this
-        # slot's active set by, so its error against this slot's realized
-        # usage is the one-slot-ahead drift of analysis.estimator_error.
-        # Runs BEFORE the refresh (the refreshed estimate hasn't been used
-        # yet) and the resulting state governs THIS slot's passes.
-        reclaim_penalty = ctrl.penalty
-        if guard_on:
-            g_err = _wd.drift_sample(carry["est"].est, node_usage)
-            g_win = _wd.push_errors(carry["g_win"], g_err)
-            g_err_q = _wd.trip_statistic(g_win, gcfg.err_quantile)
-            g_state, g_timer, _ = _wd.breaker_step(
-                carry["g_state"], carry["g_timer"], g_err_q, gcfg)
-            g_open = g_state == _wd.OPEN
-            # confidence-gated reclamation: the reclaim/migrate passes see
-            # a drift-scaled penalty, tightening their 1 - margin * P cap
-            # continuously while the breaker is still closed (slot-constant
-            # scalar -> rides the kernel cap template, wavefront sound)
-            reclaim_penalty = ctrl.penalty * _wd.penalty_scale(g_err_q, gcfg)
-            n_guard_def = carry["n_guard_deferred"]
+            # --- 4.5 estimator-drift watchdog + breaker --------------------
+            # The estimate refreshed LAST slot is what admission judged this
+            # slot's active set by, so its error against this slot's realized
+            # usage is the one-slot-ahead drift of analysis.estimator_error.
+            # Runs BEFORE the refresh (the refreshed estimate hasn't been used
+            # yet) and the resulting state governs THIS slot's passes.
+            reclaim_penalty = ctrl.penalty
+            if guard_on:
+                g_err = _wd.drift_sample(carry["est"].est, node_usage)
+                g_win = _wd.push_errors(carry["g_win"], g_err)
+                g_err_q = _wd.trip_statistic(g_win, gcfg.err_quantile)
+                g_state, g_timer, _ = _wd.breaker_step(
+                    carry["g_state"], carry["g_timer"], g_err_q, gcfg)
+                g_open = g_state == _wd.OPEN
+                # confidence-gated reclamation: the reclaim/migrate passes see
+                # a drift-scaled penalty, tightening their 1 - margin * P cap
+                # continuously while the breaker is still closed (slot-constant
+                # scalar -> rides the kernel cap template, wavefront sound)
+                reclaim_penalty = ctrl.penalty * _wd.penalty_scale(g_err_q,
+                                                                   gcfg)
+                n_guard_def = carry["n_guard_deferred"]
 
         # --- 5. estimator refresh ------------------------------------------
-        k_est = jax.random.fold_in(k_slot, 1)
-        est_state = est.refresh(carry["est"], node_usage, k_est)
-        est_adm = est_state.est
-        if guard_on:
-            # safe mode while OPEN: admission judges nodes by the estimate
-            # blended back toward their residents' REQUESTED aggregates
-            # (metrics keep reporting the raw estimate)
-            est_adm = _wd.blend_estimate(est_state.est, requested,
-                                         g_open, gcfg)
-        node = NodeState(
-            est_usage=est_adm,
-            reserved=jnp.zeros_like(node_usage),
-            requested=requested,
-            n_tasks=n_tasks,
-            src_count=src_count,
-        )
-        if faults_on:
-            if migration_on:
-                # Proactive drain: draining/overloaded nodes stop admitting
-                # (DRAIN_LOAD on their reserved row), which simultaneously
-                # excludes them as migration TARGETS — the kernel's cap
-                # filter rejects them for every task, wavefront/dedup sound
-                # because the offset is node-side (docs/kernels.md,
-                # "Source-exclusion cap").
-                avail = slot_up & ~drain_src
-                f_off = admission.fault_load_offset(avail, slot_cap)
-            else:
-                f_off = admission.fault_load_offset(slot_up, slot_cap)
-            node = admission.mask_unavailable(node, f_off)
+        with jax.named_scope("flex.slot.estimate"):
+            k_est = jax.random.fold_in(k_slot, 1)
+            est_state = est.refresh(carry["est"], node_usage, k_est)
+            est_adm = est_state.est
+            if guard_on:
+                # safe mode while OPEN: admission judges nodes by the estimate
+                # blended back toward their residents' REQUESTED aggregates
+                # (metrics keep reporting the raw estimate)
+                est_adm = _wd.blend_estimate(est_state.est, requested,
+                                             g_open, gcfg)
+            node = NodeState(
+                est_usage=est_adm,
+                reserved=jnp.zeros_like(node_usage),
+                requested=requested,
+                n_tasks=n_tasks,
+                src_count=src_count,
+            )
+            if faults_on:
+                if migration_on:
+                    # Proactive drain: draining/overloaded nodes stop admitting
+                    # (DRAIN_LOAD on their reserved row), which simultaneously
+                    # excludes them as migration TARGETS — the kernel's cap
+                    # filter rejects them for every task, wavefront/dedup sound
+                    # because the offset is node-side (docs/kernels.md,
+                    # "Source-exclusion cap").
+                    avail = slot_up & ~drain_src
+                    f_off = admission.fault_load_offset(avail, slot_cap)
+                else:
+                    f_off = admission.fault_load_offset(slot_up, slot_cap)
+                node = admission.mask_unavailable(node, f_off)
 
         # --- 5.5 live migration off draining nodes -------------------------
         # Runs BEFORE primary admission: keeping resident work beats
@@ -471,217 +485,227 @@ def simulate_core(
         # runs on its source this slot) with admit_slot UNCHANGED — progress
         # kept — at migrate_cost extra slots of runtime.
         if migration_on:
-            n_migrated = carry["n_migrated"]
-            extra_slots = carry["extra_slots"]
-            if mig_B > 0:
-                attempt = mig_pool[:mig_B]     # bandwidth budget this slot
-                avalid = attempt >= 0
-                aqi = jnp.maximum(attempt, 0)
-                node, m_idx = admission.admit_queue(
-                    migrate_policy, node, ts.request[aqi], ts.src[aqi],
-                    ts.priority[aqi], avalid, reclaim_penalty, params,
-                    use_kernel=cfg.use_kernel,
-                    interpret=cfg.kernel_interpret,
-                    batch_mode=True, topk=cfg.wavefront_topk,
-                    dedup_buckets=cfg.dedup_buckets,
-                    tie_margin=cfg.wavefront_tie_margin)
-                m_ok = avalid & (m_idx >= 0)
-                # scatter-max via helpers: padded entries (aqi clamped to 0)
-                # contribute no-op zeros instead of racing task 0's entry
-                moved = jnp.zeros((T,), jnp.int32).at[aqi].max(
-                    m_ok.astype(jnp.int32)).astype(bool)
-                target = jnp.zeros((T,), jnp.int32).at[aqi].max(
-                    jnp.where(m_ok, m_idx, 0))
-                placement_in = jnp.where(moved, target, placement_in)
-                extra_slots = extra_slots + jnp.where(
-                    moved, jnp.int32(mcfg.migrate_cost), 0)
-                n_migrated = n_migrated + jnp.sum(m_ok.astype(jnp.int32))
-                # successes leave the pool; failures retry next slot
-                head_ok = jnp.concatenate([
-                    m_ok, jnp.zeros((mcfg.pool_size - mig_B,), bool)])
-                mig_pool = jnp.where(head_ok, -1, mig_pool)
-                mig_pool = mig_pool[jnp.argsort(mig_pool < 0, stable=True)]
+            with jax.named_scope("flex.slot.migrate"):
+                n_migrated = carry["n_migrated"]
+                extra_slots = carry["extra_slots"]
+                if mig_B > 0:
+                    attempt = mig_pool[:mig_B]     # bandwidth budget this slot
+                    avalid = attempt >= 0
+                    aqi = jnp.maximum(attempt, 0)
+                    node, m_idx = admission.admit_queue(
+                        migrate_policy, node, ts.request[aqi], ts.src[aqi],
+                        ts.priority[aqi], avalid, reclaim_penalty, params,
+                        use_kernel=cfg.use_kernel,
+                        interpret=cfg.kernel_interpret,
+                        batch_mode=True, topk=cfg.wavefront_topk,
+                        dedup_buckets=cfg.dedup_buckets,
+                        tie_margin=cfg.wavefront_tie_margin)
+                    m_ok = avalid & (m_idx >= 0)
+                    # scatter-max via helpers: padded entries (aqi clamped to
+                    # 0) contribute no-op zeros instead of racing task 0's
+                    # entry
+                    moved = jnp.zeros((T,), jnp.int32).at[aqi].max(
+                        m_ok.astype(jnp.int32)).astype(bool)
+                    target = jnp.zeros((T,), jnp.int32).at[aqi].max(
+                        jnp.where(m_ok, m_idx, 0))
+                    placement_in = jnp.where(moved, target, placement_in)
+                    extra_slots = extra_slots + jnp.where(
+                        moved, jnp.int32(mcfg.migrate_cost), 0)
+                    n_migrated = n_migrated + jnp.sum(m_ok.astype(jnp.int32))
+                    # successes leave the pool; failures retry next slot
+                    head_ok = jnp.concatenate([
+                        m_ok, jnp.zeros((mcfg.pool_size - mig_B,), bool)])
+                    mig_pool = jnp.where(head_ok, -1, mig_pool)
+                    mig_pool = mig_pool[jnp.argsort(mig_pool < 0, stable=True)]
 
         # --- 6. scheduling: retries first, then new arrivals ---------------
-        queue_ids = jnp.concatenate([carry["retry"], arrivals])       # (Qr+A,)
-        if queue_order is not None:
-            # policy-defined priority queue (e.g. FlexL's LRF order, §4.3)
-            pre_valid = queue_ids >= 0
-            pre_qi = jnp.maximum(queue_ids, 0)
-            order = queue_order(ts.request[pre_qi], ts.priority[pre_qi],
-                                pre_valid)
-            queue_ids = queue_ids[order]
-        valid = queue_ids >= 0
-        qi = jnp.maximum(queue_ids, 0)
-        if backoff_on:
-            # tasks inside their backoff window stay queued, no attempt
-            ready = valid & (slot >= next_try[qi])
+        with jax.named_scope("flex.slot.admit"):
+            queue_ids = jnp.concatenate([carry["retry"], arrivals])  # (Qr+A,)
+            if queue_order is not None:
+                # policy-defined priority queue (e.g. FlexL's LRF order, §4.3)
+                pre_valid = queue_ids >= 0
+                pre_qi = jnp.maximum(queue_ids, 0)
+                order = queue_order(ts.request[pre_qi], ts.priority[pre_qi],
+                                    pre_valid)
+                queue_ids = queue_ids[order]
+            valid = queue_ids >= 0
+            qi = jnp.maximum(queue_ids, 0)
+            if backoff_on:
+                # tasks inside their backoff window stay queued, no attempt
+                ready = valid & (slot >= next_try[qi])
+                if faults_on:
+                    # A retry against a cluster with NO admitting node is a
+                    # guaranteed-infeasible attempt: defer eligibility
+                    # (deferred tasks stay queued WITHOUT consuming an
+                    # attempt, exactly
+                    # like the backoff window) until at least one node admits.
+                    any_admit = jnp.any(avail if migration_on else slot_up)
+                    ready = ready & any_admit
+            else:
+                ready = valid
+            node, placed_idx = admission.admit_queue(
+                policy, node, ts.request[qi], ts.src[qi], ts.priority[qi],
+                ready, ctrl.penalty, params,
+                use_kernel=cfg.use_kernel, interpret=cfg.kernel_interpret,
+                batch_mode=cfg.admission_mode == "wavefront",
+                topk=cfg.wavefront_topk, dedup_buckets=cfg.dedup_buckets,
+                tie_margin=cfg.wavefront_tie_margin)
+
+        # --- placements, retry queue, fault-eviction merge ----------------
+        with jax.named_scope("flex.slot.retry"):
+            ok = ready & (placed_idx >= 0)
+            # scatter placements (unique ids per slot; -1 slots write a
+            # no-op max)
+            cand_pl = jnp.where(ok, placed_idx, -1)
+            cand_sl = jnp.where(ok, slot, -1)
+            placement = placement_in.at[qi].max(cand_pl)
+            admit_slot = admit_in.at[qi].max(cand_sl)
+
+            # retry bookkeeping
+            failed = ready & (placed_idx < 0)
+            attempts = attempts.at[qi].add(failed.astype(jnp.int32))
+            if backoff_on:
+                delay = _inject.backoff_delay(
+                    attempts[qi], cfg.retry_backoff, cfg.retry_backoff_cap)
+                if jitter_on:
+                    delay = delay + jit_tab[qi]
+                # max-scatter: invalid queue slots (qi clamped to 0) contribute
+                # a no-op 0 instead of clobbering task 0's entry, and per-task
+                # next_try is monotone (later failures -> later slots + larger
+                # delays), so max IS the latest write.
+                next_try = next_try.at[qi].max(
+                    jnp.where(failed, slot + 1 + delay, 0))
+            eligible = failed & (attempts[qi] <= cfg.max_retries)
+            if backoff_on:
+                eligible = eligible | (valid & ~ready)   # deferred stay queued
+            retry_order = jnp.argsort(~eligible, stable=True)  # eligible 1st
+            sorted_ids = queue_ids[retry_order]
+            n_eligible = jnp.sum(eligible.astype(jnp.int32))
+            pos = jnp.arange(Qr, dtype=jnp.int32)
+            new_retry = jnp.where(pos < n_eligible, sorted_ids[:Qr], -1)
+            exhausted = failed & (attempts[qi] > cfg.max_retries)
+            n_dropped = (jnp.sum(exhausted.astype(jnp.int32))
+                         + jnp.maximum(n_eligible - Qr, 0))
+
+            # merge fault-evicted tasks into the rebuilt retry queue (they were
+            # resident, so they are NOT in this slot's queue): valid-first
+            # stable compaction keeps FIFO order, overflow drops or pools.
             if faults_on:
-                # A retry against a cluster with NO admitting node is a
-                # guaranteed-infeasible attempt: defer eligibility (deferred
-                # tasks stay queued WITHOUT consuming an attempt, exactly
-                # like the backoff window) until at least one node admits.
-                any_admit = jnp.any(avail if migration_on else slot_up)
-                ready = ready & any_admit
-        else:
-            ready = valid
-        node, placed_idx = admission.admit_queue(
-            policy, node, ts.request[qi], ts.src[qi], ts.priority[qi],
-            ready, ctrl.penalty, params,
-            use_kernel=cfg.use_kernel, interpret=cfg.kernel_interpret,
-            batch_mode=cfg.admission_mode == "wavefront",
-            topk=cfg.wavefront_topk, dedup_buckets=cfg.dedup_buckets,
-            tie_margin=cfg.wavefront_tie_margin)
-
-        ok = ready & (placed_idx >= 0)
-        # scatter placements (unique ids per slot; -1 slots write a no-op max)
-        cand_pl = jnp.where(ok, placed_idx, -1)
-        cand_sl = jnp.where(ok, slot, -1)
-        placement = placement_in.at[qi].max(cand_pl)
-        admit_slot = admit_in.at[qi].max(cand_sl)
-
-        # retry bookkeeping
-        failed = ready & (placed_idx < 0)
-        attempts = attempts.at[qi].add(failed.astype(jnp.int32))
-        if backoff_on:
-            delay = _inject.backoff_delay(
-                attempts[qi], cfg.retry_backoff, cfg.retry_backoff_cap)
-            if jitter_on:
-                delay = delay + jit_tab[qi]
-            # max-scatter: invalid queue slots (qi clamped to 0) contribute
-            # a no-op 0 instead of clobbering task 0's entry, and per-task
-            # next_try is monotone (later failures -> later slots + larger
-            # delays), so max IS the latest write.
-            next_try = next_try.at[qi].max(
-                jnp.where(failed, slot + 1 + delay, 0))
-        eligible = failed & (attempts[qi] <= cfg.max_retries)
-        if backoff_on:
-            eligible = eligible | (valid & ~ready)   # deferred stay queued
-        retry_order = jnp.argsort(~eligible, stable=True)   # eligible first
-        sorted_ids = queue_ids[retry_order]
-        n_eligible = jnp.sum(eligible.astype(jnp.int32))
-        pos = jnp.arange(Qr, dtype=jnp.int32)
-        new_retry = jnp.where(pos < n_eligible, sorted_ids[:Qr], -1)
-        exhausted = failed & (attempts[qi] > cfg.max_retries)
-        n_dropped = (jnp.sum(exhausted.astype(jnp.int32))
-                     + jnp.maximum(n_eligible - Qr, 0))
-
-        # merge fault-evicted tasks into the rebuilt retry queue (they were
-        # resident, so they are NOT in this slot's queue): valid-first
-        # stable compaction keeps FIFO order, overflow drops or pools.
-        if faults_on:
-            ev_ids = _compact_ids(evict_requeue, Qr)
-            ev_lost = (jnp.sum(evict_requeue.astype(jnp.int32))
-                       - jnp.sum((ev_ids >= 0).astype(jnp.int32)))
-            merged_r = jnp.concatenate([new_retry, ev_ids])
-            merged_r = merged_r[jnp.argsort(merged_r < 0, stable=True)]
-            merge_over = merged_r[Qr:]                       # overflow ids
-            new_retry = merged_r[:Qr]
-            n_dropped = (n_dropped + ev_lost
-                         + jnp.sum((evict_exhausted).astype(jnp.int32))
-                         + jnp.sum((merge_over >= 0).astype(jnp.int32)))
+                ev_ids = _compact_ids(evict_requeue, Qr)
+                ev_lost = (jnp.sum(evict_requeue.astype(jnp.int32))
+                           - jnp.sum((ev_ids >= 0).astype(jnp.int32)))
+                merged_r = jnp.concatenate([new_retry, ev_ids])
+                merged_r = merged_r[jnp.argsort(merged_r < 0, stable=True)]
+                merge_over = merged_r[Qr:]                       # overflow ids
+                new_retry = merged_r[:Qr]
+                n_dropped = (n_dropped + ev_lost
+                             + jnp.sum((evict_exhausted).astype(jnp.int32))
+                             + jnp.sum((merge_over >= 0).astype(jnp.int32)))
 
         # --- 7. headroom reclamation (opt-in) ------------------------------
         if cfg.reclamation:
-            # Permanently-dropped tasks (out of retries, or retry-queue
-            # overflow) enter a bounded pool instead of being rejected;
-            # only POOL overflow counts into n_rejected.
-            rank = jnp.argsort(retry_order)         # queue pos -> sorted pos
-            pooled = exhausted | (eligible & (rank >= Qr))
-            parts = [carry["pool"], jnp.where(pooled, queue_ids, -1)]
-            if faults_on:
-                # fault evictions feed the pool too: retry overflow,
-                # exhausted evictions, and degrade-shed victims
-                pool_evict = evict_exhausted
-                if shed_to_pool:
-                    pool_evict = pool_evict | degrade_mask
-                parts += [merge_over, _compact_ids(pool_evict, Qr)]
-            merged = jnp.concatenate(parts)
-            merged = merged[jnp.argsort(merged < 0, stable=True)]
-            pool = merged[:cfg.reclaim_pool]
-            n_rejected = carry["n_rejected"] + (
-                jnp.sum((merged >= 0).astype(jnp.int32))
-                - jnp.sum((pool >= 0).astype(jnp.int32)))
-            if faults_on:
-                n_rejected = n_rejected + ev_lost
+            with jax.named_scope("flex.slot.reclaim"):
+                # Permanently-dropped tasks (out of retries, or retry-queue
+                # overflow) enter a bounded pool instead of being rejected;
+                # only POOL overflow counts into n_rejected.
+                rank = jnp.argsort(retry_order)     # queue pos -> sorted pos
+                pooled = exhausted | (eligible & (rank >= Qr))
+                parts = [carry["pool"], jnp.where(pooled, queue_ids, -1)]
+                if faults_on:
+                    # fault evictions feed the pool too: retry overflow,
+                    # exhausted evictions, and degrade-shed victims
+                    pool_evict = evict_exhausted
+                    if shed_to_pool:
+                        pool_evict = pool_evict | degrade_mask
+                    parts += [merge_over, _compact_ids(pool_evict, Qr)]
+                merged = jnp.concatenate(parts)
+                merged = merged[jnp.argsort(merged < 0, stable=True)]
+                pool = merged[:cfg.reclaim_pool]
+                n_rejected = carry["n_rejected"] + (
+                    jnp.sum((merged >= 0).astype(jnp.int32))
+                    - jnp.sum((pool >= 0).astype(jnp.int32)))
+                if faults_on:
+                    n_rejected = n_rejected + ev_lost
 
-            # Re-admit the pool against predicted headroom: the reclaim
-            # policy judges nodes by P * L-hat + reserved against the
-            # penalty-derived cap, and the decisions run through the SAME
-            # admit_queue_wavefront path as primary admission (the
-            # reclaim policy's kernel_inputs hook + batch_mode).
-            pvalid = pool >= 0
-            pqi = jnp.maximum(pool, 0)
-            if guard_on:
-                # breaker gating: full pool while CLOSED, suspended while
-                # OPEN, a bounded head-of-pool trickle while HALF_OPEN (the
-                # pool is compacted valid-first, so the head is FIFO)
-                g_allow = (jnp.arange(cfg.reclaim_pool, dtype=jnp.int32)
-                           < _wd.reclaim_width(g_state, cfg.reclaim_pool,
-                                               gcfg))
-                n_guard_def = n_guard_def + jnp.sum(
-                    (pvalid & ~g_allow).astype(jnp.int32))
-                pvalid = pvalid & g_allow
-            node, r_idx = admission.admit_queue(
-                reclaim_policy, node, ts.request[pqi], ts.src[pqi],
-                ts.priority[pqi], pvalid, reclaim_penalty, params,
-                use_kernel=cfg.use_kernel, interpret=cfg.kernel_interpret,
-                batch_mode=True, topk=cfg.wavefront_topk,
-                dedup_buckets=cfg.dedup_buckets,
-                tie_margin=cfg.wavefront_tie_margin)
-            r_ok = pvalid & (r_idx >= 0)
-            placement = placement.at[pqi].max(jnp.where(r_ok, r_idx, -1))
-            admit_slot = admit_slot.at[pqi].max(jnp.where(r_ok, slot, -1))
-            n_reclaimed = (carry["n_reclaimed"]
-                           + jnp.sum(r_ok.astype(jnp.int32)))
-            pool = jnp.where(r_ok, -1, pool)
-            pool = pool[jnp.argsort(pool < 0, stable=True)]
-            if degrade_on:
-                # remember reclaim-admitted tasks: first in line when the
-                # degradation controller needs victims (low safety cap)
-                reclaimed_now = (reclaimed.astype(jnp.int32)
-                                 .at[pqi].max(r_ok.astype(jnp.int32)))
-                reclaimed = reclaimed_now.astype(bool)
+                # Re-admit the pool against predicted headroom: the reclaim
+                # policy judges nodes by P * L-hat + reserved against the
+                # penalty-derived cap, and the decisions run through the SAME
+                # admit_queue_wavefront path as primary admission (the
+                # reclaim policy's kernel_inputs hook + batch_mode).
+                pvalid = pool >= 0
+                pqi = jnp.maximum(pool, 0)
+                if guard_on:
+                    # breaker gating: full pool while CLOSED, suspended while
+                    # OPEN, a bounded head-of-pool trickle while HALF_OPEN (the
+                    # pool is compacted valid-first, so the head is FIFO)
+                    g_allow = (jnp.arange(cfg.reclaim_pool, dtype=jnp.int32)
+                               < _wd.reclaim_width(g_state, cfg.reclaim_pool,
+                                                   gcfg))
+                    n_guard_def = n_guard_def + jnp.sum(
+                        (pvalid & ~g_allow).astype(jnp.int32))
+                    pvalid = pvalid & g_allow
+                node, r_idx = admission.admit_queue(
+                    reclaim_policy, node, ts.request[pqi], ts.src[pqi],
+                    ts.priority[pqi], pvalid, reclaim_penalty, params,
+                    use_kernel=cfg.use_kernel, interpret=cfg.kernel_interpret,
+                    batch_mode=True, topk=cfg.wavefront_topk,
+                    dedup_buckets=cfg.dedup_buckets,
+                    tie_margin=cfg.wavefront_tie_margin)
+                r_ok = pvalid & (r_idx >= 0)
+                placement = placement.at[pqi].max(jnp.where(r_ok, r_idx, -1))
+                admit_slot = admit_slot.at[pqi].max(jnp.where(r_ok, slot, -1))
+                n_reclaimed = (carry["n_reclaimed"]
+                               + jnp.sum(r_ok.astype(jnp.int32)))
+                pool = jnp.where(r_ok, -1, pool)
+                pool = pool[jnp.argsort(pool < 0, stable=True)]
+                if degrade_on:
+                    # remember reclaim-admitted tasks: first in line when the
+                    # degradation controller needs victims (low safety cap)
+                    reclaimed_now = (reclaimed.astype(jnp.int32)
+                                     .at[pqi].max(r_ok.astype(jnp.int32)))
+                    reclaimed = reclaimed_now.astype(bool)
         else:
             n_rejected = carry["n_rejected"] + n_dropped
             n_reclaimed = jnp.zeros((), jnp.int32)
 
         # --- metrics --------------------------------------------------------
-        gate = cfg.record_node_usage
-        empty = jnp.zeros((0, NUM_RESOURCES), jnp.float32)
-        req_total = jnp.sum(node.requested + node.reserved, axis=0)
-        if faults_on:
-            req_total = req_total - jnp.sum(f_off)   # undo the fault offset
-        zero_i = jnp.zeros((), jnp.int32)
-        metrics = SlotMetrics(
-            usage=jnp.sum(node_usage, axis=0) / n_nodes,
-            requested=req_total / n_nodes,
-            qos=q_cluster,
-            penalty=ctrl.penalty,
-            usage_std=jnp.std(node_usage, axis=0),
-            usage_mean=jnp.mean(node_usage, axis=0),
-            n_running=jnp.sum(active.astype(jnp.int32)),
-            n_rejected=n_rejected,
-            node_usage=node_usage if gate else empty,
-            est_usage=jnp.sum(est_state.est, axis=0) / n_nodes,
-            node_est=est_state.est if gate else empty,
-            node_requested=requested if gate else empty,
-            n_reclaimed=n_reclaimed,
-            n_fault_evicted=n_fault_ev if faults_on else zero_i,
-            n_degrade_evicted=n_degrade_ev if degrade_on else zero_i,
-            degraded=(pressure.astype(jnp.int32) if degrade_on else zero_i),
-            n_migrated=n_migrated if migration_on else zero_i,
-            n_migration_failed=n_mig_failed if migration_on else zero_i,
-            # guard leaves are EMPTY (stacked (S, 0)) when guard=None —
-            # guard_report raises on .size == 0 and summarize degrades
-            # gracefully, mirroring the node_usage gating above
-            guard_tripped=(g_state if guard_on
-                           else jnp.zeros((0,), jnp.int32)),
-            n_guard_deferred=(n_guard_def if guard_on
-                              else jnp.zeros((0,), jnp.int32)),
-            guard_err_q=(g_err_q if guard_on
-                         else jnp.zeros((0,), jnp.float32)),
-        )
+        with jax.named_scope("flex.slot.metrics"):
+            gate = cfg.record_node_usage
+            empty = jnp.zeros((0, NUM_RESOURCES), jnp.float32)
+            req_total = jnp.sum(node.requested + node.reserved, axis=0)
+            if faults_on:
+                req_total = req_total - jnp.sum(f_off)  # undo fault offset
+            zero_i = jnp.zeros((), jnp.int32)
+            metrics = SlotMetrics(
+                usage=jnp.sum(node_usage, axis=0) / n_nodes,
+                requested=req_total / n_nodes,
+                qos=q_cluster,
+                penalty=ctrl.penalty,
+                usage_std=jnp.std(node_usage, axis=0),
+                usage_mean=jnp.mean(node_usage, axis=0),
+                n_running=jnp.sum(active.astype(jnp.int32)),
+                n_rejected=n_rejected,
+                node_usage=node_usage if gate else empty,
+                est_usage=jnp.sum(est_state.est, axis=0) / n_nodes,
+                node_est=est_state.est if gate else empty,
+                node_requested=requested if gate else empty,
+                n_reclaimed=n_reclaimed,
+                n_fault_evicted=n_fault_ev if faults_on else zero_i,
+                n_degrade_evicted=n_degrade_ev if degrade_on else zero_i,
+                degraded=(pressure.astype(jnp.int32) if degrade_on
+                          else zero_i),
+                n_migrated=n_migrated if migration_on else zero_i,
+                n_migration_failed=n_mig_failed if migration_on else zero_i,
+                # guard leaves are EMPTY (stacked (S, 0)) when guard=None —
+                # guard_report raises on .size == 0 and summarize degrades
+                # gracefully, mirroring the node_usage gating above
+                guard_tripped=(g_state if guard_on
+                               else jnp.zeros((0,), jnp.int32)),
+                n_guard_deferred=(n_guard_def if guard_on
+                                  else jnp.zeros((0,), jnp.int32)),
+                guard_err_q=(g_err_q if guard_on
+                             else jnp.zeros((0,), jnp.float32)),
+            )
 
         new_carry = dict(
             node=node, ctrl=ctrl, est=est_state, placement=placement,

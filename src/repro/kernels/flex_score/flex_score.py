@@ -165,6 +165,7 @@ def _score_topk(est, reserved, src_frac, task_mat, *, k, tile, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="flex_score_topk",
     )(est_t, res_t, src_frac, task_mat)
     return out_max[:, :Q], out_idx[:, :Q]
 

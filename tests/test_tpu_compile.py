@@ -13,6 +13,7 @@ pytest run every worker imports this file.  Keep these tests in this one
 file, so that the one worker that runs them is the one that loads it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -79,3 +80,12 @@ def test_kernel_compiles_for_v5e(kernel, N, Q, one_chip,
     fn, args = _kernel_args(kernel, N, Q, one_chip)
     compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel", ["tiles", "batch", "topk"])
+def test_kernel_keeps_its_name_on_v5e(kernel, one_chip, no_persistent_cache):
+    """Every entry point's kernel is the instruction ``flex_score_topk``,
+    so a device trace names it the same way whichever path runs it."""
+    fn, args = _kernel_args(kernel, 8, 256, one_chip)
+    text = fn.lower(*args).compile().as_text()
+    assert re.search(r"%flex_score_topk(\.\d+)? = .*tpu_custom_call", text)
