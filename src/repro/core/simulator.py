@@ -276,15 +276,8 @@ def simulate_core(
 
     def _compact_ids(mask, width):
         """Ids of set tasks, lowest index first, (width,) padded with -1."""
-        k = min(width, T)
-        keyv = jnp.where(mask, -jnp.arange(T, dtype=jnp.int32),
-                         jnp.int32(-T - 1))
-        top_val, top_idx = jax.lax.top_k(keyv, k)
-        ids = jnp.where(top_val > -T - 1, top_idx.astype(jnp.int32), -1)
-        if k < width:
-            ids = jnp.concatenate(
-                [ids, jnp.full((width - k,), -1, jnp.int32)])
-        return ids
+        ids = allocation._active_ids(mask, width)
+        return jnp.where(ids < T, ids, -1)
 
     def slot_step(carry, xs):
         if migration_on:
@@ -406,7 +399,7 @@ def simulate_core(
         # --- 3. allocation + QoS ------------------------------------------
         with jax.named_scope("flex.slot.allocate"):
             wfs_cap = (jnp.where(slot_up, slot_cap, 0.0) if faults_on else 1.0)
-            alloc, node_usage = allocation.wfs_allocate(
+            alloc, node_usage, alloc_rows = allocation._wfs_compacted(
                 demand, ts.request, placement_in, active, n_nodes,
                 capacity=wfs_cap, iters=cfg.wfs_iters)
             q_task = qos.task_qos(alloc, demand, ts.request)
@@ -705,6 +698,7 @@ def simulate_core(
                                   else jnp.zeros((0,), jnp.int32)),
                 guard_err_q=(g_err_q if guard_on
                              else jnp.zeros((0,), jnp.float32)),
+                alloc_rows=alloc_rows,
             )
 
         new_carry = dict(

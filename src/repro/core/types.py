@@ -257,6 +257,9 @@ class SlotMetrics(NamedTuple):
                                    # trickle clipping); (S, 0) unless guard
     guard_err_q: jnp.ndarray  # (S,) windowed drift-error quantile the
                               # breaker acted on; (S, 0) unless guard
+    alloc_rows: jnp.ndarray   # (S,) i32 task rows the WFS allocator ran on:
+                              # the ladder rung holding the running set, T
+                              # on the full path (core/allocation.py)
 
 
 class SimResult(NamedTuple):

@@ -1,9 +1,10 @@
 """WFS allocator (paper §3): the three cases + conservation."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import wfs_allocate
+from repro.core import allocation, wfs_allocate
 
 
 def alloc(demand, request, placement=None, n_nodes=1):
@@ -61,3 +62,68 @@ def test_inactive_get_nothing():
     a, u = wfs_allocate(d, r, jnp.asarray([0, 0], jnp.int32),
                         jnp.asarray([True, False]), 1)
     assert float(a[1].sum()) == 0.0
+
+
+# --- active-set compaction: every rung gives the full T-row bits ----------
+
+LADDER_T, LADDER_N = 8192, 64
+
+
+def _ladder_case(n_active, capacity, seed=0):
+    """An oversubscribed slot of LADDER_T tasks, n_active of them running."""
+    rng = np.random.default_rng(seed)
+    d = jnp.asarray(rng.uniform(0, 0.4, (LADDER_T, 2)), jnp.float32)
+    r = jnp.asarray(rng.uniform(0, 0.3, (LADDER_T, 2)), jnp.float32)
+    pl = jnp.asarray(rng.integers(-1, LADDER_N, LADDER_T), jnp.int32)
+    act = np.zeros(LADDER_T, bool)
+    act[rng.choice(LADDER_T, n_active, replace=False)] = True
+    if capacity == "table":   # per-node (N, R) capacity, some nodes down
+        cap = rng.uniform(0.3, 1.0, (LADDER_N, 2)).astype(np.float32)
+        cap[rng.choice(LADDER_N, 8, replace=False)] = 0.0
+        capacity = jnp.asarray(cap)
+    return d, r, pl, jnp.asarray(act), capacity
+
+
+def test_ladder_widths():
+    assert allocation._ladder(LADDER_T) == (1024, 2048, 4096)
+    assert allocation._ladder(584964) == (9216, 18432, 36864, 73728, 146432,
+                                          292864)
+    assert allocation._ladder(1024) == ()
+
+
+@pytest.mark.parametrize("n_active,rows,capacity", [
+    (0, 1024, 1.0),
+    (1, 1024, 1.0),
+    (1024, 1024, 1.0),        # at a rung's width
+    (1025, 2048, 1.0),        # one over it
+    (2048, 2048, 1.0),
+    (2049, 4096, 1.0),
+    (4096, 4096, 1.0),        # the widest compact rung
+    (4097, LADDER_T, 1.0),    # above it: the full path
+    (LADDER_T, LADDER_T, 1.0),
+    (0, 1024, "table"),
+    (700, 1024, "table"),
+    (3000, 4096, "table"),
+    (6000, LADDER_T, "table"),
+])
+def test_compacted_equals_full_rows(n_active, rows, capacity):
+    d, r, pl, act, cap = _ladder_case(n_active, capacity)
+    a0, u0 = wfs_allocate(d, r, pl, act, LADDER_N, cap)
+    a1, u1, ran = jax.jit(allocation._wfs_compacted, static_argnums=4)(
+        d, r, pl, act, LADDER_N, cap)
+    assert int(ran) == rows
+    np.testing.assert_array_equal(np.asarray(a1), np.asarray(a0))
+    np.testing.assert_array_equal(np.asarray(u1), np.asarray(u0))
+
+
+def test_compacted_vmap_takes_the_widest_rung():
+    cases = [_ladder_case(n, 1.0, seed=n) for n in (300, 1500, 3500)]
+    d, r, pl = cases[0][:3]
+    acts = jnp.stack([c[3] for c in cases])
+    a1, u1, ran = jax.vmap(
+        lambda a: allocation._wfs_compacted(d, r, pl, a, LADDER_N))(acts)
+    assert np.asarray(ran).tolist() == [4096] * 3
+    for i, act in enumerate(acts):
+        a0, u0 = wfs_allocate(d, r, pl, act, LADDER_N)
+        np.testing.assert_array_equal(np.asarray(a1[i]), np.asarray(a0))
+        np.testing.assert_array_equal(np.asarray(u1[i]), np.asarray(u0))

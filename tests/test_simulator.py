@@ -1,9 +1,11 @@
 """Simulator invariants + scheduler-differentiation system behaviour."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import SchedulerKind, SimConfig, run
+from repro.api import Experiment
+from repro.core import SchedulerKind, SimConfig, allocation, run
 from repro.traces import analysis, generate_calibrated
 
 CFG = SimConfig(n_nodes=60, n_slots=32, arrivals_per_slot=256,
@@ -62,3 +64,77 @@ def test_deterministic(ts):
     r2 = run(ts, CFG, SchedulerKind.FLEX_F, seed=7)
     np.testing.assert_array_equal(np.asarray(r1.placement),
                                   np.asarray(r2.placement))
+
+
+# A cluster whose task count gives the allocator a ladder of several rungs
+# (1024, 2048, 3072 under T = 5573) and a running set that climbs from the
+# first rung to the second within the study.
+LADDER_CFG = SimConfig(n_nodes=160, n_slots=32, arrivals_per_slot=512,
+                       retry_capacity=64, record_node_usage=True)
+
+
+@pytest.fixture(scope="module")
+def ladder_exp():
+    ts = generate_calibrated(0, LADDER_CFG.n_nodes, LADDER_CFG.n_slots, 1.5)
+    return Experiment(ts, LADDER_CFG, "flex-f")
+
+
+def test_vmapped_seeds_equal_single_runs(ladder_exp):
+    seeds = (3, 4)
+    both = ladder_exp.run(seeds=list(seeds))
+    singles = [ladder_exp.run(seeds=s) for s in seeds]
+    rows = np.asarray(both.metrics.alloc_rows)
+    # batched, every member runs on the widest rung any of them needs
+    np.testing.assert_array_equal(
+        rows, np.broadcast_to(np.max([np.asarray(s.metrics.alloc_rows)
+                                      for s in singles], axis=0), rows.shape))
+    for i, one in enumerate(singles):
+        pairs = zip(jax.tree.leaves(both._replace(
+                        metrics=both.metrics._replace(alloc_rows=None))),
+                    jax.tree.leaves(one._replace(
+                        metrics=one.metrics._replace(alloc_rows=None))))
+        for b, o in pairs:
+            b, o = np.asarray(b)[i], np.asarray(o)
+            if np.issubdtype(o.dtype, np.floating):
+                # XLA orders a batched float reduction differently: the
+                # per-node and cluster usage series differ in the last bit
+                # between batched and single runs, the full-width
+                # allocator's too
+                np.testing.assert_allclose(b, o, rtol=1e-6, atol=1e-7)
+            else:
+                np.testing.assert_array_equal(b, o)
+        for name in ("qos", "penalty"):   # the controller's series: exact
+            np.testing.assert_array_equal(
+                np.asarray(getattr(both.metrics, name))[i],
+                np.asarray(getattr(one.metrics, name)))
+        run_n = np.asarray(one.metrics.n_running)
+        ran = np.asarray(one.metrics.alloc_rows)
+        assert (ran >= run_n).all()
+        assert set(ran.tolist()) == {1024, 2048}
+    assert (rows >= np.asarray(both.metrics.n_running)).all()
+
+
+def _eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for p in e.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                if hasattr(sub, "consts") and hasattr(sub, "jaxpr"):
+                    yield from _eqns(sub.jaxpr)
+                elif hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_vmapped_ladder_is_one_cond(ladder_exp):
+    n_rungs = len(allocation._ladder(ladder_exp.trace.arrival.shape[0])) + 1
+    assert n_rungs == 4
+    keys = jnp.stack([jax.random.PRNGKey(s) for s in (0, 1)])
+    jx = jax.make_jaxpr(jax.vmap(ladder_exp._one, in_axes=(None, 0)))(
+        ladder_exp.params, keys)
+    eqns = list(_eqns(jx.jaxpr))
+    ladders = [e for e in eqns if e.primitive.name == "cond"
+               and len(e.params["branches"]) == n_rungs]
+    assert len(ladders) == 1
+    assert ladders[0].invars[0].aval.shape == ()   # one rung for the batch
+    assert not [e for e in eqns if e.primitive.name == "select_n"
+                and len(e.invars) - 1 == n_rungs]
