@@ -25,6 +25,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.custom_batching import custom_vmap
 
 from repro.core.types import FlexParams, NodeState
 
@@ -200,8 +201,8 @@ def admit_one(policy, ctx: PolicyContext, task: TaskView,
               interpret: bool = False):
     """ScheduleOne: filter, score, place on argmax; -1 when nothing fits.
 
-    All state updates are O(1) scatters so a long ``lax.scan`` over a task
-    queue stays cheap (the O(N) filter/score reduction IS the algorithm —
+    All state updates are O(1) scatters so a long loop over a task queue
+    stays cheap (the O(N) filter/score reduction IS the algorithm —
     and it is the part ``use_kernel`` routes through the Pallas kernel,
     see ``pick_node``).  Returns (new NodeState, node idx).
     """
@@ -224,6 +225,69 @@ def admit_one(policy, ctx: PolicyContext, task: TaskView,
     return new_node, idx
 
 
+@custom_vmap
+def _batch_max(x):
+    """``x``; under ``vmap``, its largest value over the batch, unbatched."""
+    return x
+
+
+@_batch_max.def_vmap
+def _batch_max_rule(axis_size, in_batched, x):
+    del axis_size, in_batched
+    return _batch_max(jnp.max(x, axis=0)), False
+
+
+def _sequential(policy, batch_mode: bool) -> bool:
+    """Whether ``admit_queue`` takes its sequential shape."""
+    return not (batch_mode
+                and getattr(policy, "kernel_inputs", None) is not None)
+
+
+def loop_steps(policy, valid, *, batch_mode: bool = False):
+    """The loop steps ``admit_queue`` runs on a queue: its ready count
+    (``sum(valid)``) on the sequential shape, 0 on the wavefront shape.
+
+    Under ``vmap`` the count is the batch's largest, unbatched: the loop
+    runs that many steps for every member (a member's surplus steps are
+    no-ops), since a batched trip count would turn the loop into a
+    ``select`` over the whole node state on every step.
+    """
+    if not _sequential(policy, batch_mode):
+        return jnp.zeros((), jnp.int32)
+    return _batch_max(jnp.sum(valid, dtype=jnp.int32))
+
+
+def _admit_ready(policy, node: NodeState, requests, srcs, priorities,
+                 valid, penalty, params: FlexParams, *, use_kernel: bool,
+                 interpret: bool):
+    """The sequential shape: ``admit_one`` on the ready entries only, in
+    queue order, one ``while`` of ``loop_steps`` steps.  An entry that is
+    not ready adds ``0 * request`` and changes nothing, so skipping it
+    gives the full scan's decisions."""
+    Q = valid.shape[0]
+    pos = jnp.arange(Q, dtype=jnp.int32)
+    count = jnp.cumsum(valid.astype(jnp.int32))
+    order = jnp.zeros((Q,), jnp.int32).at[
+        jnp.where(valid, count - 1, Q)].set(pos, mode="drop")
+    requests, srcs, priorities = (requests[order], srcs[order],
+                                  priorities[order])
+    live = pos < count[-1]
+
+    def step(i, carry):
+        ns, placed = carry
+        ctx = PolicyContext(node=ns, penalty=penalty, params=params)
+        ns, idx = admit_one(policy, ctx,
+                            TaskView(requests[i], srcs[i], priorities[i]),
+                            live[i], use_kernel=use_kernel,
+                            interpret=interpret)
+        return ns, placed.at[i].set(idx)
+
+    node, placed = jax.lax.fori_loop(
+        0, loop_steps(policy, valid), step,
+        (node, jnp.full((Q,), -1, jnp.int32)))
+    return node, jnp.where(valid, placed[jnp.maximum(count - 1, 0)], -1)
+
+
 def admit_queue(policy, node: NodeState, requests, srcs, priorities,
                 valid, penalty, params: FlexParams, *,
                 use_kernel: bool = False, interpret: bool = False,
@@ -234,10 +298,13 @@ def admit_queue(policy, node: NodeState, requests, srcs, priorities,
     requests: (Q, R); srcs/priorities/valid: (Q,).  Two execution shapes,
     decision-for-decision identical:
 
-      * sequential (default): one ``lax.scan`` over ``admit_one`` — with
-        ``use_kernel`` every decision in the scan body is one fused kernel
-        call (policies without the ``kernel_inputs`` hook silently keep
-        the reference path);
+      * sequential (default): one loop of ``admit_one`` over the entries
+        of ``valid``, moved to the front in queue order — one step per
+        ready entry, not per queue slot; with ``use_kernel`` every
+        decision in the loop body is one fused kernel call (policies
+        without the ``kernel_inputs`` hook silently keep the reference
+        path).  Under ``vmap`` the batch runs its largest ready count
+        (``loop_steps``); a member's surplus steps are no-ops;
       * ``batch_mode=True``: wavefront rounds over the BATCHED kernel
         (``admit_queue_wavefront``) for kernel-hooked policies — the whole
         queue is scored per node-table sweep instead of one task per
@@ -253,20 +320,15 @@ def admit_queue(policy, node: NodeState, requests, srcs, priorities,
     Returns (NodeState, placements (Q,) — node idx or -1).
     """
     with jax.named_scope("flex.admission"):
-        if batch_mode and getattr(policy, "kernel_inputs", None) is not None:
+        if not _sequential(policy, batch_mode):
             return admit_queue_wavefront(policy, node, requests, srcs,
                                          priorities, valid, penalty, params,
                                          interpret=interpret, topk=topk,
                                          dedup_buckets=dedup_buckets,
                                          tie_margin=tie_margin)
-
-        def step(ns, xs):
-            r, src, prio, ok = xs
-            ctx = PolicyContext(node=ns, penalty=penalty, params=params)
-            return admit_one(policy, ctx, TaskView(r, src, prio), ok,
-                             use_kernel=use_kernel, interpret=interpret)
-
-        return jax.lax.scan(step, node, (requests, srcs, priorities, valid))
+        return _admit_ready(policy, node, requests, srcs, priorities, valid,
+                            penalty, params, use_kernel=use_kernel,
+                            interpret=interpret)
 
 
 def make_queue_admitter(policy, params: FlexParams, *,

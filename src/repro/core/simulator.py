@@ -2,7 +2,8 @@
 
 Replaces the paper's event-driven Go Kubernetes simulator with a slot-based
 JAX program: one ``lax.scan`` over 5-minute slots (the Google trace's usage
-sampling period), an inner ``lax.scan`` over the slot's scheduling queue.
+sampling period), an inner loop over the ready entries of the slot's
+scheduling queue.
 A 4000-node / 700k-task / 24-h evaluation is ONE compiled XLA program.
 
 Placement is pluggable: the simulator is generic over a
@@ -536,13 +537,16 @@ def simulate_core(
                     ready = ready & any_admit
             else:
                 ready = valid
+            wavefront = cfg.admission_mode == "wavefront"
             node, placed_idx = admission.admit_queue(
                 policy, node, ts.request[qi], ts.src[qi], ts.priority[qi],
                 ready, ctrl.penalty, params,
                 use_kernel=cfg.use_kernel, interpret=cfg.kernel_interpret,
-                batch_mode=cfg.admission_mode == "wavefront",
+                batch_mode=wavefront,
                 topk=cfg.wavefront_topk, dedup_buckets=cfg.dedup_buckets,
                 tie_margin=cfg.wavefront_tie_margin)
+            admit_steps = admission.loop_steps(policy, ready,
+                                               batch_mode=wavefront)
 
         # --- placements, retry queue, fault-eviction merge ----------------
         with jax.named_scope("flex.slot.retry"):
@@ -699,6 +703,7 @@ def simulate_core(
                 guard_err_q=(g_err_q if guard_on
                              else jnp.zeros((0,), jnp.float32)),
                 alloc_rows=alloc_rows,
+                admit_steps=admit_steps,
             )
 
         new_carry = dict(

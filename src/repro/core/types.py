@@ -260,6 +260,10 @@ class SlotMetrics(NamedTuple):
     alloc_rows: jnp.ndarray   # (S,) i32 task rows the WFS allocator ran on:
                               # the ladder rung holding the running set, T
                               # on the full path (core/allocation.py)
+    admit_steps: jnp.ndarray  # (S,) i32 steps of the sequential admission
+                              # loop: one per ready queue entry, the batch's
+                              # largest count under vmap (api/admission.py);
+                              # 0 on the wavefront path
 
 
 class SimResult(NamedTuple):

@@ -47,7 +47,8 @@ decision-identical:
     kept as the reference baseline the serving benchmark measures
     speedups against;
   ``"sequential"`` — one jitted ``admit_queue`` call per step: the
-    ``lax.scan`` over ``admit_one``, whole pending queue per launch;
+    loop of ``admit_one`` over its ready entries, whole pending queue
+    per launch;
   ``"wavefront"``  — ``admit_queue(batch_mode=True)``: the batched
     top-K candidate kernel with conflict-resolution rounds (PR 3/4),
     scoring the whole queue per node-table sweep.  The default.

@@ -84,15 +84,20 @@ def test_vmapped_seeds_equal_single_runs(ladder_exp):
     both = ladder_exp.run(seeds=list(seeds))
     singles = [ladder_exp.run(seeds=s) for s in seeds]
     rows = np.asarray(both.metrics.alloc_rows)
-    # batched, every member runs on the widest rung any of them needs
-    np.testing.assert_array_equal(
-        rows, np.broadcast_to(np.max([np.asarray(s.metrics.alloc_rows)
-                                      for s in singles], axis=0), rows.shape))
+    # batched, every member runs on the widest rung any of them needs, and
+    # the admission loop runs the largest ready count of the batch
+    for name in ("alloc_rows", "admit_steps"):
+        got = np.asarray(getattr(both.metrics, name))
+        np.testing.assert_array_equal(
+            got, np.broadcast_to(np.max([np.asarray(getattr(s.metrics, name))
+                                         for s in singles], axis=0),
+                                 got.shape))
+    batch_wide = dict(alloc_rows=None, admit_steps=None)
     for i, one in enumerate(singles):
         pairs = zip(jax.tree.leaves(both._replace(
-                        metrics=both.metrics._replace(alloc_rows=None))),
+                        metrics=both.metrics._replace(**batch_wide))),
                     jax.tree.leaves(one._replace(
-                        metrics=one.metrics._replace(alloc_rows=None))))
+                        metrics=one.metrics._replace(**batch_wide))))
         for b, o in pairs:
             b, o = np.asarray(b)[i], np.asarray(o)
             if np.issubdtype(o.dtype, np.floating):
