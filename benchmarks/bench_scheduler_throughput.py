@@ -9,12 +9,12 @@ of nodes).  Four sections:
   * ``admit_wavefront_*``: wavefront batched admission vs the sequential
     per-task kernel path for N in {512, 2048, 8192} x Q in {64, 512} —
     parity-asserted placement-for-placement, with the conflict-round count
-    and node-sweep reduction (Q sweeps -> rounds sweeps) in the derived
-    column.  Three variants per grid point: the legacy one-sweep-per-round
-    loop (``admit_wavefront_*``, topk=0), the top-K candidate-caching loop
-    (``admit_wavefront_topk_*``, K=8 + score-bucket dedup), and — at
-    N=2048, Q=512 — a duplicate-heavy queue (8 job shapes x 8 sources,
-    ``admit_wavefront_topk_dup_*``) that exercises the dedup fast path.
+    and node-sweep reduction (Q sweeps -> epoch sweeps) in the derived
+    column.  The wavefront rows (``admit_wavefront_k8_*``) run the
+    admission core's top-K candidate cache with its default tuning (K=8,
+    score-bucket dedup), and — at N=2048, Q=512 — a duplicate-heavy queue
+    (8 job shapes x 8 sources, ``admit_wavefront_k8_dup_*``) exercises
+    the dedup fast path.
     ``python benchmarks/run.py --json bench_scheduler_throughput``
     merge-appends these rows into BENCH_scheduler_throughput.json so the
     perf trajectory across PRs is trackable.
@@ -26,7 +26,7 @@ of nodes).  Four sections:
     understates the TPU gain; the ``node_sweeps_ratio`` column is the
     backend-independent measure.
 
-The queue goes through the open-policy admission core (``schedule_queue``
+The queue goes through the open-policy admission core (``admit_queue``
 with a registry policy object), so new policies inherit this bench."""
 import time
 
@@ -35,7 +35,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import Row
 from repro.api import admission, get_policy
-from repro.core import FlexParams, NodeState, schedule_queue
+from repro.core import FlexParams, NodeState
 from repro.kernels.flex_score.ops import flex_pick_node
 from repro.kernels.flex_score.ref import pick_node_ref
 
@@ -68,12 +68,12 @@ def run(full: bool):
         node = NodeState.zeros(n)
         node = node._replace(est_usage=jax.random.uniform(key, (n, 2)) * 0.5)
         reqs = jax.random.uniform(key, (Q, 2)) * 0.1
-        srcs = jnp.zeros((Q,), jnp.int32)
+        srcs = prios = jnp.zeros((Q,), jnp.int32)
         valid = jnp.ones((Q,), bool)
         pen = jnp.asarray(1.2)
 
-        f_ref = jax.jit(lambda nd: schedule_queue(
-            nd, reqs, srcs, valid, pen, params, policy))
+        f_ref = jax.jit(lambda nd: admission.admit_queue(
+            policy, nd, reqs, srcs, prios, valid, pen, params))
         us = _time(lambda nd: f_ref(nd)[1], node, iters=5) / Q
         rows.append(Row(f"schedule_one_n{n}", us,
                         {"nodes": n, "decisions_per_s": 1e6 / us}))
@@ -82,8 +82,8 @@ def run(full: bool):
         # or as its interpreter build (anywhere) — the dispatch in
         # flex_pick_node would silently fall back to the reference on
         # plain CPU and time the same program twice.
-        f_ker = jax.jit(lambda nd: schedule_queue(
-            nd, reqs, srcs, valid, pen, params, policy,
+        f_ker = jax.jit(lambda nd: admission.admit_queue(
+            policy, nd, reqs, srcs, prios, valid, pen, params,
             use_kernel=True, interpret=not on_tpu))
         us_k = _time(lambda nd: f_ker(nd)[1], node, iters=5) / Q
         rows.append(Row(f"schedule_one_kernel_n{n}", us_k,
@@ -125,21 +125,14 @@ def run(full: bool):
         f_seq = jax.jit(lambda nd: admission.admit_queue(
             policy, nd, reqs, srcs, prios, valid, pen, params,
             use_kernel=True, interpret=not on_tpu))
-        f_wave = jax.jit(lambda nd: admission.admit_queue_wavefront(
-            policy, nd, reqs, srcs, prios, valid, pen, params,
-            interpret=not on_tpu, topk=0, with_rounds=True))
         f_topk = jax.jit(lambda nd: admission.admit_queue_wavefront(
             policy, nd, reqs, srcs, prios, valid, pen, params,
-            interpret=not on_tpu, topk=8, dedup_buckets=64,
-            with_rounds=True))
+            interpret=not on_tpu, with_rounds=True))
 
-        # parity gate: both wavefront flavors must reproduce the
-        # sequential decisions before anything is timed
+        # parity gate: the wavefront must reproduce the sequential
+        # decisions before anything is timed
         pl_seq = f_seq(node)[1]
-        _, pl_wave, w_rounds, w_sweeps = f_wave(node)
         _, pl_topk, t_rounds, t_sweeps = f_topk(node)
-        assert (pl_seq == pl_wave).all(), (
-            f"wavefront/sequential disagree at N={n} Q={q}")
         assert (pl_seq == pl_topk).all(), (
             f"topk-wavefront/sequential disagree at N={n} Q={q}")
 
@@ -149,21 +142,11 @@ def run(full: bool):
                        {"nodes": n, "queue": q,
                         "decisions_per_s": 1e6 / us_seq,
                         "interpret": interp}))
-        us_wave = _time(lambda nd: f_wave(nd)[1], node, iters=3) / q
-        out.append(Row(f"admit_wavefront_{tag}", us_wave,
-                       {"nodes": n, "queue": q,
-                        "decisions_per_s": 1e6 / us_wave,
-                        "speedup_vs_seq": us_seq / us_wave,
-                        "rounds": int(w_rounds),
-                        "sweeps": int(w_sweeps),
-                        "node_sweeps_ratio": q / max(int(w_sweeps), 1),
-                        "interpret": interp}))
         us_topk = _time(lambda nd: f_topk(nd)[1], node, iters=3) / q
-        out.append(Row(f"admit_wavefront_topk_{tag}", us_topk,
+        out.append(Row(f"admit_wavefront_k8_{tag}", us_topk,
                        {"nodes": n, "queue": q,
                         "decisions_per_s": 1e6 / us_topk,
                         "speedup_vs_seq": us_seq / us_topk,
-                        "speedup_vs_wavefront": us_wave / us_topk,
                         "rounds": int(t_rounds),
                         "sweeps": int(t_sweeps),
                         "node_sweeps_ratio": q / max(int(t_sweeps), 1),

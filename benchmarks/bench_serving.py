@@ -88,7 +88,7 @@ def run(full: bool):
     base = None
     for mode in ("eager", "sequential", "wavefront"):
         cfg = EngineConfig(n_replicas=4, kv_budget_tokens=65536,
-                           policy="flex", max_active_per_replica=64,
+                           policy="flex-f", max_active_per_replica=64,
                            admission_mode=mode, admit_batch=256)
         eng = ServeEngine(cfg)
         _warm_admitter(eng)
@@ -111,7 +111,7 @@ def run(full: bool):
     rate = 64.0 if full else 24.0
     for pattern in ARRIVAL_PATTERNS:
         cfg = EngineConfig(n_replicas=8, kv_budget_tokens=8192,
-                           policy="flex", max_active_per_replica=64,
+                           policy="flex-f", max_active_per_replica=64,
                            admission_mode="wavefront", admit_batch=256)
         eng = ServeEngine(cfg)
         _warm_admitter(eng)

@@ -15,7 +15,7 @@ kernel:
       reclamation=True``: the reclaim pass admits its 256-wide pool
       through the batched kernel.  Its summary must be finite.
   (c) serving — ``repro.serving.ServeEngine`` with 8 replicas, policy
-      ``flex`` and its default wavefront admission, driven open-loop by a
+      ``flex-f`` and its default wavefront admission, driven open-loop by a
       burst ``RequestStream``; the same stream under sequential admission
       must give equal admitted, finished and decision counts.
 
@@ -149,7 +149,7 @@ def run_engine(mode, horizon, steps, rate):
     from repro.serving.engine import EngineConfig, ServeEngine
     from repro.serving.stream import RequestStream, StreamConfig
 
-    eng = ServeEngine(EngineConfig(n_replicas=8, policy="flex",
+    eng = ServeEngine(EngineConfig(n_replicas=8, policy="flex-f",
                                    admission_mode=mode))
     # Compile the admission entry at every pad width the engine uses
     # (powers of two from 8 up to admit_batch) before the stream starts.
@@ -185,7 +185,7 @@ def run_engine(mode, horizon, steps, rate):
 
 
 def phase_serving(errors, horizon=80, steps=100, rate=24.0):
-    print(f"phase c: serving replicas=8 policy=flex pattern=burst "
+    print(f"phase c: serving replicas=8 policy=flex-f pattern=burst "
           f"rate={rate} horizon={horizon} steps={steps}", flush=True)
     wav, n_wav = run_engine("wavefront", horizon, steps, rate)
     seq, n_seq = run_engine("sequential", horizon, steps, rate)

@@ -1,4 +1,4 @@
-"""Serving example: Flex vs reserve admission over REAL model decode.
+"""Serving example: Flex vs LeastFit admission over REAL model decode.
 
 Each replica holds a live slot-batched KV cache of a reduced stablelm;
 requests over-declare max_tokens (like Google-trace users over-request).
@@ -19,7 +19,7 @@ ROOT = Path(__file__).resolve().parent.parent
 
 
 def main():
-    for policy in ("reserve", "flex"):
+    for policy in ("least-fit", "flex-f"):
         print(f"=== policy: {policy} ===", flush=True)
         subprocess.run(
             [sys.executable, "-m", "repro.launch.serve",

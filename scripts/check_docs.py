@@ -15,10 +15,10 @@ Fails (exit 1 / non-empty problem list) when:
     ``docs/kernels.md`` lost its "Batched wavefront admission" section;
   * the kernel package exposes the top-K candidate primitive but
     ``docs/kernels.md`` lost its "Top-K candidate lists" section;
-  * ``SimConfig`` carries wavefront tuning knobs (``wavefront_topk``,
-    ``dedup_buckets``, ``wavefront_tie_margin``) or estimator/reclamation
-    knobs (``estimator``, ``reclamation``, ``reclaim_margin``,
-    ``reclaim_pool``) that ``docs/api.md`` does not document;
+  * ``SimConfig`` carries admission-path knobs (``admission_mode``,
+    ``kernel_interpret``) or estimator/reclamation knobs (``estimator``,
+    ``reclamation``, ``reclaim_margin``, ``reclaim_pool``) that
+    ``docs/api.md`` does not document;
   * an estimator registered in ``repro.estimators`` is missing from the
     "Estimators" table in ``docs/api.md`` (or the table lists a name
     that is not registered);
@@ -131,7 +131,7 @@ def problems() -> list:
                 "but docs/kernels.md has no 'Top-K candidate lists' section")
 
     from repro.core.types import SimConfig
-    for knob in ("wavefront_topk", "dedup_buckets", "wavefront_tie_margin",
+    for knob in ("admission_mode", "kernel_interpret",
                  "estimator", "reclamation", "reclaim_margin",
                  "reclaim_pool", "retry_backoff", "retry_backoff_cap",
                  "retry_jitter", "faults", "migration", "guard"):

@@ -38,7 +38,6 @@ from repro.api.protocols import (  # noqa: F401
     policy_supports_kernel,
 )
 from repro.api.registry import (  # noqa: F401
-    KIND_TO_NAME,
     get_policy,
     list_policies,
     register_policy,

@@ -10,6 +10,10 @@ One set of filter/score primitives used by BOTH execution substrates:
     slot + KV resources (bit-identical placements:
     tests/test_serving_parity.py).
 
+``admit_queue`` is the one way into the admission loop; its wavefront
+shape is tuned by the module constants ``TOPK``, ``DEDUP_BUCKETS`` and
+``TIE_MARGIN``, which change sweeps and rounds but no decision.
+
 Every helper is written against the array *methods / operators* shared by
 ``numpy`` and ``jax.numpy`` (plus an explicit ``where`` dispatch), so the
 two paths cannot drift apart again: an admission rule is expressed once.
@@ -37,6 +41,14 @@ NEG_INF = -1e30
 # rejects every candidate.  The single shared sentinel — the serving engine
 # and the fault/migration offsets all import it from here.
 DRAIN_LOAD = 1e6
+
+# Wavefront tuning (``admit_queue_wavefront``): cached (score, node)
+# candidates per task per node-table sweep, the score-bucket dedup width
+# (``NUM_SRC_BUCKETS``), and the relative margin of the conflict checks.
+# They move sweeps and rounds, never decisions (docs/kernels.md).
+TOPK = 8
+DEDUP_BUCKETS = 64
+TIE_MARGIN = 1e-5
 
 
 def _xp(x):
@@ -291,8 +303,7 @@ def _admit_ready(policy, node: NodeState, requests, srcs, priorities,
 def admit_queue(policy, node: NodeState, requests, srcs, priorities,
                 valid, penalty, params: FlexParams, *,
                 use_kernel: bool = False, interpret: bool = False,
-                batch_mode: bool = False, topk: int = 8,
-                dedup_buckets: int = 64, tie_margin: float = 1e-5):
+                batch_mode: bool = False):
     """Admit a padded queue of tasks in queue order.
 
     requests: (Q, R); srcs/priorities/valid: (Q,).  Two execution shapes,
@@ -308,10 +319,8 @@ def admit_queue(policy, node: NodeState, requests, srcs, priorities,
       * ``batch_mode=True``: wavefront rounds over the BATCHED kernel
         (``admit_queue_wavefront``) for kernel-hooked policies — the whole
         queue is scored per node-table sweep instead of one task per
-        sweep.  ``topk``/``dedup_buckets``/``tie_margin`` tune that path
-        (see ``admit_queue_wavefront``; they are ignored by the
-        sequential scan).  Policies without the hook silently fall back
-        to the sequential scan.
+        sweep.  Policies without the hook silently fall back to the
+        sequential scan.
 
     Both shapes run under the named scope ``flex.admission``, so a device
     trace shows the admission core's ops under that path whatever pass
@@ -323,9 +332,7 @@ def admit_queue(policy, node: NodeState, requests, srcs, priorities,
         if not _sequential(policy, batch_mode):
             return admit_queue_wavefront(policy, node, requests, srcs,
                                          priorities, valid, penalty, params,
-                                         interpret=interpret, topk=topk,
-                                         dedup_buckets=dedup_buckets,
-                                         tie_margin=tie_margin)
+                                         interpret=interpret)
         return _admit_ready(policy, node, requests, srcs, priorities, valid,
                             penalty, params, use_kernel=use_kernel,
                             interpret=interpret)
@@ -333,13 +340,12 @@ def admit_queue(policy, node: NodeState, requests, srcs, priorities,
 
 def make_queue_admitter(policy, params: FlexParams, *,
                         batch_mode: bool = False, use_kernel: bool = False,
-                        interpret: bool = False, topk: int = 8,
-                        dedup_buckets: int = 64, tie_margin: float = 1e-5):
+                        interpret: bool = False):
     """Compile one reusable admission entry point for a fixed policy.
 
     The serving engine (and any other eager caller that admits queues
     repeatedly against changing state) should not re-trace
-    :func:`admit_queue` per call: the policy object, the wavefront knobs
+    :func:`admit_queue` per call: the policy object, the execution flags
     and the static queue width fully determine the XLA program.  This
     wraps ``admit_queue`` in a ``jax.jit`` whose only traced inputs are
     the live cluster state — ``(node, requests, srcs, priorities, valid,
@@ -349,7 +355,7 @@ def make_queue_admitter(policy, params: FlexParams, *,
     ``params`` is bound after the policy's ``prepare_params``
     normalization (e.g. ULB policies pin theta), exactly as the
     simulator does before its scan — but TRACED, not closed over, so
-    every admitter for the same (policy, knobs) shares one jit cache:
+    every admitter for the same (policy, flags) shares one jit cache:
     constructing many engines (the parity property suite builds
     hundreds) compiles each queue width once, not once per engine.
 
@@ -359,8 +365,7 @@ def make_queue_admitter(policy, params: FlexParams, *,
     from repro.api.protocols import policy_prepare_params
 
     prepared = policy_prepare_params(policy, params)
-    fn = _shared_queue_admitter(policy, batch_mode, use_kernel, interpret,
-                                topk, dedup_buckets, tie_margin)
+    fn = _shared_queue_admitter(policy, batch_mode, use_kernel, interpret)
 
     def admit(node, requests, srcs, priorities, valid, penalty):
         return fn(node, requests, srcs, priorities, valid, penalty, prepared)
@@ -369,9 +374,8 @@ def make_queue_admitter(policy, params: FlexParams, *,
 
 
 @functools.lru_cache(maxsize=64)
-def _shared_queue_admitter(policy, batch_mode, use_kernel, interpret,
-                           topk, dedup_buckets, tie_margin):
-    """One jitted admit_queue per (policy, static knobs) — see
+def _shared_queue_admitter(policy, batch_mode, use_kernel, interpret):
+    """One jitted admit_queue per (policy, static flags) — see
     :func:`make_queue_admitter`.  Policies are frozen dataclasses, so
     they hash; FlexParams rides in as a traced pytree."""
 
@@ -380,9 +384,7 @@ def _shared_queue_admitter(policy, batch_mode, use_kernel, interpret,
         return admit_queue(policy, node, requests, srcs, priorities,
                            valid, penalty, params,
                            use_kernel=use_kernel, interpret=interpret,
-                           batch_mode=batch_mode, topk=topk,
-                           dedup_buckets=dedup_buckets,
-                           tie_margin=tie_margin)
+                           batch_mode=batch_mode)
 
     return admit
 
@@ -410,8 +412,8 @@ def _batched_kernel_inputs(policy, ctx: PolicyContext, tasks: TaskView):
 def admit_queue_wavefront(policy, node: NodeState, requests, srcs,
                           priorities, valid, penalty, params: FlexParams, *,
                           interpret: bool = False, tile: int = 512,
-                          tie_margin: float = 1e-5, topk: int = 8,
-                          dedup_buckets: int = 64,
+                          tie_margin: float = TIE_MARGIN, topk: int = TOPK,
+                          dedup_buckets: int = DEDUP_BUCKETS,
                           with_rounds: bool = False):
     """Admit the queue in conflict-resolution rounds over the batched kernel.
 
@@ -427,17 +429,14 @@ def admit_queue_wavefront(policy, node: NodeState, requests, srcs,
     runs only when the head pending task exhausts its cached candidates
     or a dirtied node provably threatens its candidate score (the same
     beat-check machinery that guards intra-round commits).  The number of
-    sweeps drops from Q (sequential) or #rounds (the ``topk=0`` legacy
-    loop below) to #epochs: one on low-conflict queues, ~Q/(3K) under
-    conflict-heavy Flex scoring where each sweep's lists go stale after
-    ~3K commits dirty the shared least-loaded frontier
-    (docs/kernels.md cost model; BENCH_scheduler_throughput.json).
+    sweeps drops from Q (sequential) to #epochs: one on low-conflict
+    queues, ~Q/(3K) under conflict-heavy Flex scoring where each sweep's
+    lists go stale after ~3K commits dirty the shared least-loaded
+    frontier (docs/kernels.md cost model; BENCH_scheduler_throughput.json).
 
-    With ``topk=0`` the pre-candidate-cache behavior is kept: every
-    conflict round re-sweeps the node table with the argmax kernel
-    (``flex_pick_node_batch``) — one sweep per round.  This path exists
-    for comparison benchmarks and as an escape hatch; decisions are
-    identical either way.
+    ``topk`` must be at least 1; ``topk``, ``dedup_buckets`` and
+    ``tie_margin`` default to the module constants ``TOPK``,
+    ``DEDUP_BUCKETS`` and ``TIE_MARGIN``.
 
     A **score-bucket dedup** (``dedup_buckets`` > 0) additionally shrinks
     each sweep: under the kernel template a task's whole (N,) score row
@@ -516,12 +515,13 @@ def admit_queue_wavefront(policy, node: NodeState, requests, srcs,
 
     Returns (NodeState, placements (Q,)) — plus (rounds, sweeps) when
     ``with_rounds`` (static flag) is set: ``rounds`` counts commit
-    rounds, ``sweeps`` counts node-table sweeps (kernel launches); the
-    legacy ``topk=0`` loop launches once per round, so there
-    rounds == sweeps.
+    rounds, ``sweeps`` counts node-table sweeps (kernel launches).
     """
-    from repro.kernels.flex_score.ops import (flex_pick_node_batch,
-                                              flex_pick_node_batch_topk)
+    from repro.kernels.flex_score.ops import flex_pick_node_batch_topk
+
+    if topk < 1:
+        raise ValueError(f"admit_queue_wavefront: topk must be >= 1, "
+                         f"got {topk}")
 
     requests = jnp.asarray(requests, jnp.float32)
     Q, R = requests.shape
@@ -574,53 +574,8 @@ def admit_queue_wavefront(policy, node: NodeState, requests, srcs,
         earlier_lead = lead[None, :] & (pos[None, :] < pos[:, None])
         return jnp.any(beats & earlier_lead, axis=1)
 
-    if topk == 0:
-        # Legacy loop (PR 3): one full batched argmax sweep per round.
-        def round_body(state):
-            ns, pending, placement, rounds = state
-            ctx = PolicyContext(node=ns, penalty=penalty, params=params)
-            ki = _batched_kernel_inputs(policy, ctx, tasks)
-            cand, best, feas = flex_pick_node_batch(
-                ki.est_usage, ki.reserved, ki.src_frac, requests, ki.penalty,
-                w_load=ki.w_load, w_src=ki.w_src, cap=ki.cap, tile=tile,
-                interpret=interpret)
-
-            # Tasks with no feasible node finalize -1 now (placement
-            # already -1); the rest are this round's wavefront.
-            pending_f = pending & feas
-            cc = jnp.clip(cand, 0, N - 1)
-
-            # dup: an earlier pending task already picked this node.
-            first_at = jnp.full((N,), Q, jnp.int32).at[cc].min(
-                jnp.where(pending_f, pos, Q))
-            dup = pending_f & (first_at[cc] < pos)
-            lead = pending_f & ~dup   # first picker of each candidate node
-
-            beat = _post_commit_beat(ns, ki, cc, best, lead)
-
-            # Commit the prefix before the first unsafe task; everything
-            # after it rolls to the next round (its decision could change
-            # theirs).
-            unsafe = pending_f & (dup | beat)
-            first_unsafe = jnp.min(jnp.where(unsafe, pos, Q))
-            commit = pending_f & (pos < first_unsafe)
-
-            ns = _commit_state(ns, commit, cc)
-            placement = jnp.where(commit, cand, placement)
-            return ns, pending_f & ~commit, placement, rounds + 1
-
-        init = (node, valid, jnp.full((Q,), -1, jnp.int32),
-                jnp.zeros((), jnp.int32))
-        node, _, placement, rounds = jax.lax.while_loop(
-            lambda s: jnp.any(s[1]), round_body, init)
-        if with_rounds:
-            return node, placement, rounds, rounds
-        return node, placement
-
-    # ------------------------------------------------------------------
-    # Candidate-caching path: sweep once per EPOCH, fall back through the
-    # cached top-K lists between sweeps.
-    # ------------------------------------------------------------------
+    # Sweep once per EPOCH, fall back through the cached top-K lists
+    # between sweeps.
     K = int(topk)
     use_dedup = 0 < int(dedup_buckets) < Q
 

@@ -9,8 +9,8 @@ a 10-seed x 8-theta study is a single device program:
     res = exp.run(seeds=range(10))                       # leaves: (10, S, ...)
     res = exp.run(seeds=[0, 1], sweep=[p1, p2, p3])      # leaves: (3, 2, S, ...)
 
-Policies are registry names, ``SchedulerKind`` values or policy objects —
-anything ``repro.api.registry.resolve_policy`` accepts.
+Policies are registry names or policy objects — anything
+``repro.api.registry.resolve_policy`` accepts.
 """
 from __future__ import annotations
 
@@ -45,13 +45,13 @@ class Experiment:
                  controller=None):
         self.trace = trace
         self.cluster = cluster if cluster is not None else SimConfig()
-        # Same normalization as the legacy simulate() entry point (one
+        # Same normalization as the simulate() entry point (one
         # implementation — the two front-ends cannot drift).  ``estimator``
         # may be a repro.estimators registry name or an estimator object;
         # None defers to SimConfig.estimator, then "current".
         (self.policy, self.params, self.estimator,
          self.controller) = simulator._resolve(
-            policy, params, estimator, "current", est_noise_std, controller,
+            policy, params, estimator, est_noise_std, controller,
             self.cluster)
         self._table = None
 

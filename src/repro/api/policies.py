@@ -1,8 +1,7 @@
 """Built-in policies, estimators and controllers (paper §4 + extensions).
 
 The four paper policies (LeastFit, Oversub, FlexF, FlexL) are expressed
-through the shared admission helpers with exactly the seed repo's math, so
-the ``SchedulerKind`` shim is numerically identical to the registry path.
+through the shared admission helpers with exactly the seed repo's math.
 Two extra policies (``best-fit-usage``, ``flex-priority``) demonstrate the
 open registry: neither exists in the paper.
 

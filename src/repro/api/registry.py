@@ -12,25 +12,15 @@ importing their classes:
 
     policy = get_policy("my-policy")
 
-``resolve_policy`` additionally accepts a legacy ``SchedulerKind`` (the
-seed repo's closed enum) or an already-constructed policy object, so every
-historical call site funnels into the same open API.
+``resolve_policy`` additionally passes an already-constructed policy
+object through, so the simulator and the serving engine take a policy by
+its registry name or as an object, and by nothing else.
 """
 from __future__ import annotations
 
 from typing import Callable, Dict, List
 
-from repro.core.types import SchedulerKind
-
 _POLICIES: Dict[str, Callable[[], object]] = {}
-
-# SchedulerKind -> registry name (the thin compatibility shim).
-KIND_TO_NAME = {
-    SchedulerKind.LEAST_FIT: "least-fit",
-    SchedulerKind.OVERSUB: "oversub",
-    SchedulerKind.FLEX_F: "flex-f",
-    SchedulerKind.FLEX_L: "flex-l",
-}
 
 
 def register_policy(name: str, factory: Callable[[], object] | None = None):
@@ -87,9 +77,8 @@ def list_policies() -> List[str]:
 
 
 def resolve_policy(policy):
-    """str | SchedulerKind | PlacementPolicy -> PlacementPolicy."""
-    if isinstance(policy, SchedulerKind):
-        return get_policy(KIND_TO_NAME[policy])
+    """str | PlacementPolicy -> PlacementPolicy; unknown names raise
+    ``KeyError`` listing the registered ones."""
     if isinstance(policy, str):
         return get_policy(policy)
     return policy

@@ -11,7 +11,6 @@ from repro.core.types import (  # noqa: F401
     ControllerState,
     FlexParams,
     NodeState,
-    SchedulerKind,
     SimConfig,
     SimResult,
     SlotMetrics,
@@ -21,9 +20,6 @@ from repro.core.penalty import update_penalty  # noqa: F401
 from repro.core.schedulers import (  # noqa: F401
     fifo_scheduler,
     lrf_scheduler,
-    node_scores,
-    place_task,
-    schedule_queue,
 )
 from repro.core.allocation import waterfill, wfs_allocate  # noqa: F401
 from repro.core.qos import cluster_qos, task_qos, violation_fraction  # noqa: F401

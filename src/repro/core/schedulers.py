@@ -1,18 +1,9 @@
-"""Placement entry points (paper §4, Algorithms 1-3) — legacy shim layer.
+"""Phase-1 single-resource schedulers (paper §4.1, Algorithms 1-2).
 
-The actual admission loop lives in ``repro.api.admission`` (one shared
-filter/score core) and the policies in ``repro.api.policies`` (an open
-registry).  This module keeps the seed repo's function signatures working:
-``node_scores`` / ``place_task`` / ``schedule_queue`` accept either a
-``SchedulerKind`` (resolved through the registry shim) or any
-``PlacementPolicy`` object, and delegate to the shared core.
-
-Sequential semantics are preserved exactly: tasks are placed one at a time
-via ``lax.scan`` and every decision sees the previous placement's
-reservation, as in Kubernetes.
-
-The phase-1 single-resource schedulers (``fifo_scheduler`` /
-``lrf_scheduler``, Theorems 4.1-4.2) remain here as reference semantics.
+``fifo_scheduler`` / ``lrf_scheduler`` (Theorems 4.1-4.2) are reference
+semantics with precise load estimation.  Multi-resource admission
+(Alg. 3) lives in ``repro.api.admission`` and the policies in the
+``repro.api`` registry.
 """
 from __future__ import annotations
 
@@ -20,107 +11,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro.core.types import FlexParams, NodeState
-
-_NEG_INF = -1e30
-
-
-def _ctx_task(node, r_task, src_bucket, penalty, params):
-    from repro.api.admission import PolicyContext, TaskView
-    ctx = PolicyContext(node=node, penalty=penalty, params=params)
-    task = TaskView(request=r_task, src=src_bucket,
-                    priority=jnp.zeros((), jnp.int32))
-    return ctx, task
-
-
-def node_scores(
-    node: NodeState,
-    r_task: jnp.ndarray,        # (R,) request of the task being placed
-    src_bucket: jnp.ndarray,    # () i32
-    penalty: jnp.ndarray,       # () f32
-    params: FlexParams,
-    kind,                       # SchedulerKind | registry name | policy
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Filter + score all nodes for one task.
-
-    Returns (scores (N,), feasible (N,) bool).  Infeasible nodes get -inf.
-    """
-    from repro.api.admission import mask_infeasible
-    from repro.api.registry import resolve_policy
-
-    policy = resolve_policy(kind)
-    ctx, task = _ctx_task(node, r_task, src_bucket, penalty, params)
-    feasible = policy.feasible(ctx, task)
-    scores = mask_infeasible(policy.score(ctx, task), feasible)
-    return scores, feasible
-
-
-def place_task(
-    node: NodeState,
-    r_task: jnp.ndarray,
-    src_bucket: jnp.ndarray,
-    valid: jnp.ndarray,         # () bool — False => no-op (padding entry)
-    penalty: jnp.ndarray,
-    params: FlexParams,
-    kind,
-    use_kernel: bool = False,
-    interpret: bool = False,
-) -> Tuple[NodeState, jnp.ndarray]:
-    """ScheduleOne (Alg. 3): returns (new_state, node_idx); idx = -1 on failure.
-
-    ``use_kernel``/``interpret`` select the fused Pallas filter+score path
-    for kernel-capable policies (docs/kernels.md).
-    """
-    from repro.api.admission import admit_one
-    from repro.api.registry import resolve_policy
-
-    policy = resolve_policy(kind)
-    ctx, task = _ctx_task(node, r_task, src_bucket, penalty, params)
-    return admit_one(policy, ctx, task, valid,
-                     use_kernel=use_kernel, interpret=interpret)
-
-
-def schedule_queue(
-    node: NodeState,
-    requests: jnp.ndarray,     # (Q, R) padded task requests
-    src_buckets: jnp.ndarray,  # (Q,) i32
-    valid: jnp.ndarray,        # (Q,) bool — False for padding entries
-    penalty: jnp.ndarray,
-    params: FlexParams,
-    kind,
-    priorities: jnp.ndarray | None = None,  # (Q,) i32; None = CLASS_BATCH
-    use_kernel: bool = False,
-    interpret: bool = False,
-    batch_mode: bool = False,
-    topk: int = 8,
-    dedup_buckets: int = 64,
-    tie_margin: float = 1e-5,
-) -> Tuple[NodeState, jnp.ndarray]:
-    """Place a queue of tasks in queue order.  Returns (state, placements (Q,)).
-
-    The queue is admitted IN THE ORDER GIVEN — a policy's ``queue_order``
-    hook is the caller's concern (the simulator applies it before calling
-    in).  Priority-aware policies (e.g. ``flex-priority``) need
-    ``priorities``; it defaults to all-batch when omitted.
-    ``use_kernel``/``interpret`` select the fused Pallas filter+score path
-    for kernel-capable policies; ``batch_mode`` admits the queue in
-    wavefront rounds over the batched top-K kernel instead of the
-    sequential scan — same decisions, fewer node-table sweeps
-    (``topk``/``dedup_buckets``/``tie_margin`` tune it, docs/kernels.md).
-    """
-    from repro.api.admission import admit_queue
-    from repro.api.registry import resolve_policy
-
-    policy = resolve_policy(kind)
-    if priorities is None:
-        priorities = jnp.zeros_like(src_buckets)
-    return admit_queue(policy, node, requests, src_buckets, priorities,
-                       valid, penalty, params,
-                       use_kernel=use_kernel, interpret=interpret,
-                       batch_mode=batch_mode, topk=topk,
-                       dedup_buckets=dedup_buckets, tie_margin=tie_margin)
-
 
 # ---------------------------------------------------------------------------
 # Phase-1 algorithms with precise load estimation (paper §4.1).

@@ -8,9 +8,8 @@ A 4000-node / 700k-task / 24-h evaluation is ONE compiled XLA program.
 
 Placement is pluggable: the simulator is generic over a
 ``repro.api.PlacementPolicy`` object (plus an ``Estimator`` and a
-``PenaltyController``), all static jit arguments.  The legacy
-``SchedulerKind`` enum still works everywhere a policy is accepted — it is
-resolved through the registry shim (``repro.api.registry.KIND_TO_NAME``).
+``PenaltyController``), all static jit arguments.  A policy is named by
+its registry name or passed as an object (``repro.api.resolve_policy``).
 
 Per-slot pipeline (semantics match Kubernetes + Alg. 3):
   0. with faults (``SimConfig(faults=...)`` or an explicit
@@ -61,21 +60,20 @@ through the scan); legacy stateless estimators are adapted
 bit-identically.  ``SimConfig(estimator="quantile")`` selects one by
 registry name.
 
-Execution substrate of step 6 (the hot path): with
-``SimConfig(use_kernel=True)`` every ScheduleOne decision in the inner
-scan dispatches to the fused Pallas filter+score kernel
-(``repro.kernels.flex_score``) for policies that expose the
-``kernel_inputs`` hook — one kernel call per placement, the whole decision
-step compiles into the scan body.  ``SimConfig(admission_mode="wavefront")``
-replaces the per-task scan with conflict-resolution rounds over the
-BATCHED kernel: one top-K sweep (``wavefront_topk``, score-bucket dedup
-via ``dedup_buckets``) caches per-task candidate lists and the rounds
-fall back through them, re-sweeping only when a candidate list is
-provably stale (decisions stay bit-identical to the sequential scan —
-docs/kernels.md; ``wavefront_tie_margin`` tunes the conservatism).
-``kernel_interpret=True`` runs either kernel through the Pallas
-interpreter (pure XLA) so CPU tests exercise the identical tiling/masking
-logic; see docs/kernels.md.
+Execution substrate of step 6 (the hot path): every pass admits through
+``admission.admit_queue``.  With ``SimConfig(use_kernel=True)`` every
+ScheduleOne decision in the inner loop dispatches to the fused Pallas
+filter+score kernel (``repro.kernels.flex_score``) for policies that
+expose the ``kernel_inputs`` hook — one kernel call per placement, the
+whole decision step compiles into the loop body.
+``SimConfig(admission_mode="wavefront")`` replaces the per-task loop with
+conflict-resolution rounds over the BATCHED kernel: one top-K sweep caches
+per-task candidate lists and the rounds fall back through them,
+re-sweeping only when a candidate list is provably stale (decisions stay
+bit-identical to the sequential loop — docs/kernels.md; the sweep's
+tuning is the admission core's constants).  ``kernel_interpret=True``
+runs either kernel through the Pallas interpreter (pure XLA) so CPU tests
+exercise the identical tiling/masking logic; see docs/kernels.md.
 """
 from __future__ import annotations
 
@@ -490,10 +488,7 @@ def simulate_core(
                         migrate_policy, node, ts.request[aqi], ts.src[aqi],
                         ts.priority[aqi], avalid, reclaim_penalty, params,
                         use_kernel=cfg.use_kernel,
-                        interpret=cfg.kernel_interpret,
-                        batch_mode=True, topk=cfg.wavefront_topk,
-                        dedup_buckets=cfg.dedup_buckets,
-                        tie_margin=cfg.wavefront_tie_margin)
+                        interpret=cfg.kernel_interpret, batch_mode=True)
                     m_ok = avalid & (m_idx >= 0)
                     # scatter-max via helpers: padded entries (aqi clamped to
                     # 0) contribute no-op zeros instead of racing task 0's
@@ -542,9 +537,7 @@ def simulate_core(
                 policy, node, ts.request[qi], ts.src[qi], ts.priority[qi],
                 ready, ctrl.penalty, params,
                 use_kernel=cfg.use_kernel, interpret=cfg.kernel_interpret,
-                batch_mode=wavefront,
-                topk=cfg.wavefront_topk, dedup_buckets=cfg.dedup_buckets,
-                tie_margin=cfg.wavefront_tie_margin)
+                batch_mode=wavefront)
             admit_steps = admission.loop_steps(policy, ready,
                                                batch_mode=wavefront)
 
@@ -645,9 +638,7 @@ def simulate_core(
                     reclaim_policy, node, ts.request[pqi], ts.src[pqi],
                     ts.priority[pqi], pvalid, reclaim_penalty, params,
                     use_kernel=cfg.use_kernel, interpret=cfg.kernel_interpret,
-                    batch_mode=True, topk=cfg.wavefront_topk,
-                    dedup_buckets=cfg.dedup_buckets,
-                    tie_margin=cfg.wavefront_tie_margin)
+                    batch_mode=True)
                 r_ok = pvalid & (r_idx >= 0)
                 placement = placement.at[pqi].max(jnp.where(r_ok, r_idx, -1))
                 admit_slot = admit_slot.at[pqi].max(jnp.where(r_ok, slot, -1))
@@ -756,13 +747,13 @@ def simulate_core(
     )
 
 
-def _resolve(policy, params, estimator, estimator_kind, est_noise_std,
-             controller, cfg: SimConfig | None = None):
+def _resolve(policy, params, estimator, est_noise_std, controller,
+             cfg: SimConfig | None = None):
     """Normalize the open-API knobs into static jit arguments.
 
     Estimator precedence: an explicit ``estimator`` argument (object or
     registry name) wins, then a non-empty ``SimConfig.estimator``, then
-    the legacy ``estimator_kind`` string.
+    ``"current"``.
     """
     from repro.api.policies import (AimdPenaltyController, resolve_estimator)
     from repro.api.protocols import (policy_default_params,
@@ -775,7 +766,7 @@ def _resolve(policy, params, estimator, estimator_kind, est_noise_std,
     params = policy_prepare_params(policy, params)
     if estimator is None:
         estimator = (cfg.estimator if cfg is not None and cfg.estimator
-                     else estimator_kind)
+                     else "current")
     est = resolve_estimator(estimator, est_noise_std)
     ctrl_impl = controller if controller is not None else AimdPenaltyController()
     return policy, params, est, ctrl_impl
@@ -783,22 +774,20 @@ def _resolve(policy, params, estimator, estimator_kind, est_noise_std,
 
 def simulate(ts: TaskSet, arrival_table: jnp.ndarray, cfg: SimConfig,
              policy, params: FlexParams, key: jax.Array,
-             estimator_kind: str = "current", est_noise_std: float = 0.0,
-             estimator=None, controller=None,
+             est_noise_std: float = 0.0, estimator=None, controller=None,
              fault_schedule=None) -> SimResult:
     """Jitted simulation with policy/estimator/controller normalization.
 
-    ``policy`` may be a registry name, a ``SchedulerKind`` (legacy shim) or
-    a PlacementPolicy object; ``estimator`` takes a ``repro.estimators``
-    registry name or an estimator object (stateful or legacy stateless),
-    ``SimConfig(estimator=...)`` selects one from the config, and
-    ``estimator_kind`` keeps the historical string knob working.
+    ``policy`` may be a registry name or a PlacementPolicy object;
+    ``estimator`` takes a ``repro.estimators`` registry name or an
+    estimator object (stateful or legacy stateless), and
+    ``SimConfig(estimator=...)`` selects one from the config when it is
+    None (``"current"`` when both are unset).
     ``fault_schedule`` injects an explicit ``repro.faults.FaultSchedule``
     (overrides the sampling that ``SimConfig(faults=...)`` would do).
     """
     policy, params, est, ctrl_impl = _resolve(
-        policy, params, estimator, estimator_kind, est_noise_std, controller,
-        cfg)
+        policy, params, estimator, est_noise_std, controller, cfg)
     return simulate_core(ts, arrival_table, cfg, policy, params, key,
                          est, ctrl_impl, fault_schedule)
 

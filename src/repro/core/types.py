@@ -6,7 +6,6 @@ function is written generically over the trailing resource axis.
 """
 from __future__ import annotations
 
-import enum
 from typing import NamedTuple
 
 import jax.numpy as jnp
@@ -25,15 +24,6 @@ NUM_CLASSES = 3
 # Number of hash buckets for task "sources" (users/jobs).  The Flex scoring
 # rule prefers nodes with fewer tasks from the same source (§4.3).
 NUM_SRC_BUCKETS = 64
-
-
-class SchedulerKind(enum.IntEnum):
-    """Which placement policy the simulator / engine runs."""
-
-    LEAST_FIT = 0   # request-based, theta = 1       (paper baseline "LeastFit")
-    OVERSUB = 1     # request-based, theta = 2       (paper baseline "Oversub")
-    FLEX_F = 2      # usage-based, FIFO queue        (paper "FlexF")
-    FLEX_L = 3      # usage-based, LRF priority queue (paper "FlexL")
 
 
 class FlexParams(NamedTuple):
@@ -151,22 +141,9 @@ class SimConfig(NamedTuple):
                                         # hook keep the sequential scan.
     max_retries: int = 16          # admission failures before a task is dropped
                                    # (counted into n_rejected); static for jit
-    wavefront_topk: int = 8        # cached (score, node) candidates per task
-                                   # per wavefront sweep; conflict rounds fall
-                                   # back through the list instead of
-                                   # re-sweeping the node table.  0 = legacy
-                                   # one-sweep-per-round loop (docs/kernels.md)
-    dedup_buckets: int = 64        # score-bucket dedup width for wavefront
-                                   # sweeps: <= this many distinct task rows
-                                   # collapse the kernel's task matrix to one
-                                   # row per bucket.  0 disables dedup
-    wavefront_tie_margin: float = 1e-5  # relative margin of the wavefront
-                                        # conflict checks: larger = more
-                                        # conservative (extra rounds/sweeps,
-                                        # never wrong decisions)
     estimator: str = ""            # registry name for the load estimator
-                                   # (repro.estimators); "" keeps the caller's
-                                   # estimator/estimator_kind arguments
+                                   # (repro.estimators) when the caller passes
+                                   # no estimator; "" = "current"
     reclamation: bool = False      # per-slot headroom-reclamation pass:
                                    # re-admit dropped tasks against predicted
                                    # usage via the 'reclaim' policy through

@@ -2,8 +2,8 @@
 
 ``current`` and ``ewma`` are the stateful re-expressions of the seed
 repo's :mod:`repro.core.estimator` stub — same jnp expressions, same
-operation order, so the historical ``estimator_kind``/``est_noise_std``
-knobs stay bit-identical (tests/test_estimators.py proves it).
+operation order, so the historical ``est_noise_std`` knob and the legacy
+stateless classes stay bit-identical (tests/test_estimators.py proves it).
 
 ``quantile`` is the sliding peak-window predictor the related work uses
 for right-sizing (Lu & Chen's demand prediction, Beloglazov & Buyya's

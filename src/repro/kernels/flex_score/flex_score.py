@@ -7,11 +7,10 @@ against a block of queued tasks on the VPU, and reduces per task to the
 tile's best ``k`` (score, node) candidates; the tiny cross-tile merge
 happens in jnp in the wrappers.
 
-One kernel body serves all three entry points: the per-task decision
-(``flex_score_tiles``, one task, k = 1), the batched wavefront argmax
-(``flex_score_batch_tiles``, Q tasks, k = 1) and the top-K candidate
-lists (``flex_score_batch_topk_tiles``).  With k = 1 the single peel IS
-the argmax, so the three agree bit-for-bit by construction.
+One kernel body serves both entry points: the per-task decision
+(``flex_score_tiles``, one task, k = 1) and the batched top-K candidate
+lists of a whole queue (``flex_score_batch_topk_tiles``).  With k = 1 the
+single peel IS the argmax, so the two agree bit-for-bit by construction.
 
 Layout and conventions are documented in docs/kernels.md
 ("TPU block-shape rules").  Points that matter for correctness:
@@ -189,37 +188,19 @@ def flex_score_tiles(est, reserved, src_frac, task_vec, *, tile=512,
     return tmax[:, 0, 0], tidx[:, 0, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def flex_score_batch_tiles(est, reserved, src_frac, task_mat, *, tile=512,
-                           interpret=False):
-    """Per-tile (max score, argmax) partials for a WHOLE queue of tasks.
-
-    est/reserved: (N, R); src_frac: (Q, N) — one same-source-fraction row
-    per queued task; task_mat: (Q, R+4), each row packed as
-    ``[r..., penalty, cap, w_load, w_src]`` (the per-task analogue of
-    ``flex_score_tiles``'s single task vector).
-
-    One node slab is loaded ONCE per tile and scored against all Q tasks
-    (docs/kernels.md, "Batched wavefront admission").  N and Q are
-    arbitrary (zero-padded + masked tail, pad rows sliced off).
-
-    Returns (tile_max (ntiles, Q), tile_idx (ntiles, Q)); tile_idx holds
-    GLOBAL node indices, -1 where a tile is infeasible for that task.
-    """
-    tmax, tidx = _score_topk(est, reserved, src_frac, task_mat, k=1,
-                             tile=tile, interpret=interpret)
-    return tmax[..., 0], tidx[..., 0]
-
-
 @functools.partial(jax.jit, static_argnames=("k", "tile", "interpret"))
 def flex_score_batch_topk_tiles(est, reserved, src_frac, task_mat, *, k=8,
                                 tile=512, interpret=False):
     """Per-tile top-``k`` (score, idx) candidate partials for a whole queue.
 
-    Same inputs and padding rules as ``flex_score_batch_tiles``; instead
-    of one (max, argmax) pair per tile, each grid step emits its ``k``
-    best candidates per task (sorted by score desc, node idx asc — see
-    ``_topk_kernel``).
+    est/reserved: (N, R); src_frac: (Q, N) — one same-source-fraction row
+    per queued task; task_mat: (Q, R+4), each row packed as
+    ``[r..., penalty, cap, w_load, w_src]`` (the per-task analogue of
+    ``flex_score_tiles``'s single task vector).  One node slab is loaded
+    ONCE per tile and scored against all Q tasks; N and Q are arbitrary
+    (zero-padded + masked tail, pad rows sliced off).  Each grid step
+    emits its ``k`` best candidates per task (sorted by score desc, node
+    idx asc — see ``_topk_kernel``).
 
     Returns (tile_max (ntiles*k, Q), tile_idx (ntiles*k, Q)): row
     ``t*k + j`` holds tile ``t``'s (j+1)-th best candidate for each task,

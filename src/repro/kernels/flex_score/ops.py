@@ -12,11 +12,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flex_score.flex_score import (NEG_INF,
-                                                 flex_score_batch_tiles,
                                                  flex_score_batch_topk_tiles,
                                                  flex_score_tiles)
-from repro.kernels.flex_score.ref import (pick_node_batch_ref,
-                                          pick_node_batch_topk_ref,
+from repro.kernels.flex_score.ref import (pick_node_batch_topk_ref,
                                           pick_node_ref)
 
 
@@ -74,31 +72,17 @@ def flex_pick_node(est, reserved, src_frac, r_task, penalty, *,
     return idx, best, any_feasible
 
 
-def _check_batch_args(caller, est, src_frac, r_task, penalty, cap, w_load,
-                      w_src):
-    """Shared (Q, R)/(Q, N) shape check + scalar broadcast of the batched
-    wrappers.  Returns (r_task, penalty, cap, w_load, w_src) as f32 with
-    the four scalars broadcast to (Q,)."""
-    r_task = jnp.asarray(r_task, jnp.float32)
-    Q = r_task.shape[0]
-    if r_task.shape != (Q, est.shape[1]) or src_frac.shape != (Q, est.shape[0]):
-        raise ValueError(
-            f"{caller}: expected r_task (Q, R)={Q, est.shape[1]} "
-            f"and src_frac (Q, N)={Q, est.shape[0]}, got {r_task.shape} and "
-            f"{src_frac.shape}")
-    bcast = lambda x: jnp.broadcast_to(
-        jnp.asarray(x, jnp.float32).reshape(-1), (Q,))
-    return (r_task,) + tuple(map(bcast, (penalty, cap, w_load, w_src)))
+def flex_pick_node_batch_topk(est, reserved, src_frac, r_task, penalty, *,
+                              w_load, w_src, cap, k=8, tile=512,
+                              interpret=False):
+    """Top-``k`` candidate lists for the whole queue in one batched pass.
 
-
-def flex_pick_node_batch(est, reserved, src_frac, r_task, penalty, *,
-                         w_load, w_src, cap, tile=512, interpret=False):
-    """One batched filter+score+argmax pass over the whole queue.
-
-    The wavefront-admission primitive (docs/kernels.md, "Batched wavefront
-    admission"): every node tile is loaded once and scored against all Q
-    queued tasks, amortizing the per-decision kernel launch + HBM sweep of
-    ``flex_pick_node`` across the queue.
+    The wavefront-admission primitive (docs/kernels.md, "Batched
+    wavefront admission" and "Top-K candidate lists"): every node tile is
+    loaded once and scored against all Q queued tasks (k cheap VPU peels
+    per tile), and each task walks away with its k best (score, node)
+    candidates, so conflict-resolution rounds can fall back through the
+    cached list instead of re-sweeping the table.
 
     Args:
       est / reserved: (N, R) f32 — node-side load state, shared by every
@@ -107,64 +91,31 @@ def flex_pick_node_batch(est, reserved, src_frac, r_task, penalty, *,
       r_task: (Q, R) f32 — declared requests.
       penalty / w_load / w_src / cap: scalar or (Q,) — per-task scalars of
         the kernel template; scalars are broadcast to the queue.
+      k: candidates per task (static).
       tile / interpret: as in ``flex_pick_node``.
 
     Dispatch mirrors ``flex_pick_node``: Pallas when ``interpret=True`` or
-    on TPU, the batched reference einsum otherwise — all three agree
-    bit-for-bit, row for row, with the per-task primitive.
-
-    Returns (node_idx (Q,), best_score (Q,), any_feasible (Q,)).
-    """
-    r_task, penalty, cap, w_load, w_src = _check_batch_args(
-        "flex_pick_node_batch", est, src_frac, r_task, penalty, cap,
-        w_load, w_src)
-    use_pallas = interpret or jax.default_backend() == "tpu"
-    if not use_pallas:
-        return pick_node_batch_ref(est, reserved,
-                                   src_frac.astype(jnp.float32), r_task,
-                                   penalty, w_load, w_src, cap=cap)
-    task_mat = jnp.concatenate([
-        r_task, penalty[:, None], cap[:, None],
-        w_load[:, None], w_src[:, None]], axis=1)       # (Q, R+4)
-    tmax, tidx = flex_score_batch_tiles(est, reserved,
-                                        src_frac.astype(jnp.float32),
-                                        task_mat, tile=tile,
-                                        interpret=interpret)
-    t = jnp.argmax(tmax, axis=0)                        # (Q,) winning tile
-    best = jnp.take_along_axis(tmax, t[None, :], axis=0)[0]
-    any_feasible = best > NEG_INF / 2
-    idx = jnp.where(any_feasible,
-                    jnp.take_along_axis(tidx, t[None, :], axis=0)[0],
-                    -1).astype(jnp.int32)
-    return idx, best, any_feasible
-
-
-def flex_pick_node_batch_topk(est, reserved, src_frac, r_task, penalty, *,
-                              w_load, w_src, cap, k=8, tile=512,
-                              interpret=False):
-    """Top-``k`` candidate lists for the whole queue in one batched pass.
-
-    The candidate-caching wavefront primitive (docs/kernels.md, "Top-K
-    candidate lists"): same sweep cost as ``flex_pick_node_batch`` (one
-    node-table pass, k cheap VPU peels per tile) but each task walks away
-    with its k best (score, node) candidates, so conflict-resolution
-    rounds can fall back through the cached list instead of re-sweeping
-    the table.
-
-    Args are those of ``flex_pick_node_batch`` plus ``k`` (static).  The
-    Pallas path emits per-tile k-lists and this wrapper K-way-merges them
-    with ``jax.lax.top_k`` over the tile-major candidate axis; because
+    on TPU, the batched reference otherwise.  The Pallas path emits
+    per-tile k-lists and this wrapper K-way-merges them with
+    ``jax.lax.top_k`` over the tile-major candidate axis; because
     per-tile lists and tile order are both (score desc, node idx asc),
     the merged list equals the full-table ``pick_node_batch_topk_ref``
-    bit-for-bit, column for column — with k=1 it reduces exactly to the
-    ``flex_pick_node_batch`` argmax.
+    bit-for-bit, column for column — with k=1 column 0 is the batched
+    argmax, row for row the per-task ``flex_pick_node`` decision.
 
     Returns (idx (Q, k), score (Q, k), any_feasible (Q,)); slots past a
     task's feasible-node count are (-1, NEG_INF).
     """
-    r_task, penalty, cap, w_load, w_src = _check_batch_args(
-        "flex_pick_node_batch_topk", est, src_frac, r_task, penalty, cap,
-        w_load, w_src)
+    r_task = jnp.asarray(r_task, jnp.float32)
+    Q = r_task.shape[0]
+    if r_task.shape != (Q, est.shape[1]) or src_frac.shape != (Q, est.shape[0]):
+        raise ValueError(
+            f"flex_pick_node_batch_topk: expected r_task (Q, R)="
+            f"{Q, est.shape[1]} and src_frac (Q, N)={Q, est.shape[0]}, got "
+            f"{r_task.shape} and {src_frac.shape}")
+    bcast = lambda x: jnp.broadcast_to(
+        jnp.asarray(x, jnp.float32).reshape(-1), (Q,))
+    penalty, cap, w_load, w_src = map(bcast, (penalty, cap, w_load, w_src))
     use_pallas = interpret or jax.default_backend() == "tpu"
     if not use_pallas:
         return pick_node_batch_topk_ref(est, reserved,

@@ -8,8 +8,8 @@ decode step per non-empty replica.  Flex (usage-based admission + penalty
 feedback) decides which replica takes each request — the paper's scheduler
 running over actual accelerator memory.
 
-  PYTHONPATH=src python -m repro.launch.serve --policy flex --requests 64
-  PYTHONPATH=src python -m repro.launch.serve --policy reserve --requests 64
+  PYTHONPATH=src python -m repro.launch.serve --policy flex-f --requests 64
+  PYTHONPATH=src python -m repro.launch.serve --policy least-fit --requests 64
   # open-loop at production rate (arrival patterns from traces.generator):
   PYTHONPATH=src python -m repro.launch.serve --stream burst --rate 2 \
       --steps 200 --mode wavefront
@@ -125,9 +125,9 @@ def main():
     enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-3b")
-    ap.add_argument("--policy", default="flex",
-                    help="'flex'/'reserve' or any repro.api.registry policy "
-                         "name (flex-priority, best-fit-usage, ...)")
+    ap.add_argument("--policy", default="flex-f",
+                    help="a repro.api.registry policy name (flex-f, "
+                         "least-fit, flex-priority, best-fit-usage, ...)")
     ap.add_argument("--mode", choices=ADMISSION_MODES, default="wavefront",
                     help="admission execution mode (EngineConfig"
                          ".admission_mode)")

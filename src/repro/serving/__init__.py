@@ -1,11 +1,9 @@
 from repro.serving.engine import (  # noqa: F401
     ADMISSION_MODES,
-    AdmissionPolicy,
     EngineConfig,
     EngineStats,
     Request,
     ServeEngine,
-    resolve_engine_policy,
 )
 from repro.serving.stream import (  # noqa: F401
     RequestStream,

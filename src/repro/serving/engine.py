@@ -12,17 +12,15 @@ This is the paper's scenario re-instantiated for LLM inference:
 
 Admission runs through the SAME core as the discrete-time simulator
 (``repro.api.admission`` + the policy registry), so the serving path and
-the simulator cannot drift apart.  ``EngineConfig.policy`` accepts the
-legacy enum (``AdmissionPolicy.RESERVE``/``FLEX``), its string values
-(``"reserve"``/``"flex"``), ANY policy name registered in
-``repro.api.registry`` (``"flex-priority"``, ``"best-fit-usage"``, ...),
-or a policy object; unknown names raise ``KeyError`` with the registered
-list.  The enum values resolve to registry policies:
+the simulator cannot drift apart.  ``EngineConfig.policy`` is a policy
+name registered in ``repro.api.registry`` or a policy object, resolved by
+the simulator's own ``resolve_policy``; unknown names raise ``KeyError``
+with the registered list.  The two the serving benchmark compares:
 
-  RESERVE -> ``least-fit``: admit only if the DECLARED footprints of all
+  ``least-fit``: admit only if the DECLARED footprints of all
     co-resident requests fit the replica budget (request-based baseline);
-  FLEX -> ``flex-f``: admit if ``P * estimated usage + reserved + r``
-    fits — usage-based ULB placement with the estimation-penalty
+  ``flex-f`` (the default): admit if ``P * estimated usage + reserved +
+    r`` fits — usage-based ULB placement with the estimation-penalty
     controller and same-source spreading.
 
 Replicas are mapped onto the simulator's :class:`NodeState` with TWO
@@ -50,8 +48,9 @@ decision-identical:
     loop of ``admit_one`` over its ready entries, whole pending queue
     per launch;
   ``"wavefront"``  — ``admit_queue(batch_mode=True)``: the batched
-    top-K candidate kernel with conflict-resolution rounds (PR 3/4),
-    scoring the whole queue per node-table sweep.  The default.
+    top-K candidate kernel with conflict-resolution rounds, scoring the
+    whole queue per node-table sweep (tuned by the admission core's
+    constants).  The default.
 
 Straggler mitigation: replicas report a step-time EMA; slow replicas get
 their load ESTIMATE inflated by ``straggler_weight * max(ema/mean - 1,
@@ -84,7 +83,6 @@ stub).  Open-loop arrival driving lives in ``repro.serving.stream``.
 from __future__ import annotations
 
 import dataclasses
-import enum
 import time
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional
@@ -95,7 +93,7 @@ import numpy as np
 
 from repro.api import admission
 from repro.api.protocols import policy_prepare_params, policy_queue_order
-from repro.api.registry import get_policy
+from repro.api.registry import resolve_policy
 from repro.core.penalty import update_penalty
 from repro.core.types import (
     CLASS_PRODUCTION,
@@ -118,38 +116,6 @@ KV_AXIS = MEM     # KV tokens, normalized by kv_budget_tokens
 _DRAIN_LOAD = admission.DRAIN_LOAD
 
 ADMISSION_MODES = ("eager", "sequential", "wavefront")
-
-
-class AdmissionPolicy(enum.Enum):
-    RESERVE = "reserve"   # request-based (baseline) -> registry "least-fit"
-    FLEX = "flex"         # usage-based + penalty feedback -> "flex-f"
-
-
-_ENUM_TO_REGISTRY = {
-    AdmissionPolicy.RESERVE: "least-fit",
-    AdmissionPolicy.FLEX: "flex-f",
-}
-
-
-def resolve_engine_policy(policy):
-    """enum | str | PlacementPolicy -> PlacementPolicy, via the registry.
-
-    The legacy enum (and its string values ``"reserve"``/``"flex"``)
-    resolve to the registry policies with the same semantics; any other
-    string is looked up in ``repro.api.registry`` directly, so every
-    registered policy is a valid serving policy.  Unknown names raise
-    ``KeyError`` naming the registered policies — they do NOT fall
-    through to some default semantics.
-    """
-    if isinstance(policy, AdmissionPolicy):
-        return get_policy(_ENUM_TO_REGISTRY[policy])
-    if isinstance(policy, str):
-        try:
-            policy = AdmissionPolicy(policy)
-        except ValueError:
-            return get_policy(policy)    # KeyError on unknown names
-        return get_policy(_ENUM_TO_REGISTRY[policy])
-    return policy
 
 
 @dataclasses.dataclass
@@ -181,7 +147,7 @@ class Request:
 class EngineConfig:
     n_replicas: int = 4
     kv_budget_tokens: int = 8192       # per-replica KV capacity
-    policy: "AdmissionPolicy | str | object" = AdmissionPolicy.FLEX
+    policy: "str | object" = "flex-f"  # registry name or policy object
     estimator: "str | object" = "current"  # repro.estimators registry name
                                            # (or estimator object) feeding the
                                            # FLEX load estimate L-hat
@@ -193,12 +159,6 @@ class EngineConfig:
     admit_batch: int = 256             # static pad width per admission call;
                                        # longer queues admit in chunks that
                                        # carry the reservation state exactly
-    wavefront_topk: int = 8            # cached candidates per task per sweep
-                                       # (admit_queue_wavefront; 0 = legacy
-                                       # one-sweep-per-round loop)
-    dedup_buckets: int = 64            # score-bucket dedup width for the
-                                       # wavefront sweep; 0 disables
-    wavefront_tie_margin: float = 1e-5  # conflict-check conservatism
     kernel_interpret: bool = False     # run Pallas kernels via the interpreter
                                        # (CPU parity testing; off = reference
                                        # einsum on non-TPU backends)
@@ -270,7 +230,7 @@ class ServeEngine:
                 f"unknown admission_mode {cfg.admission_mode!r}; "
                 f"one of {ADMISSION_MODES}")
         self.cfg = cfg
-        self.policy = resolve_engine_policy(cfg.policy)
+        self.policy = resolve_policy(cfg.policy)
         self.decode_fn = decode_fn or self._stub_decode
         base = flex_params or FlexParams.default(
             qos_target=cfg.qos_target,
@@ -309,10 +269,7 @@ class ServeEngine:
                 MigratePolicy(margin_scale=cfg.migration.margin_scale),
                 self.params,
                 batch_mode=cfg.admission_mode == "wavefront",
-                interpret=cfg.kernel_interpret,
-                topk=cfg.wavefront_topk,
-                dedup_buckets=cfg.dedup_buckets,
-                tie_margin=cfg.wavefront_tie_margin)
+                interpret=cfg.kernel_interpret)
         # Load estimator (same registry as the simulator): refreshed once
         # per round from measured KV footprints; ``_usage_snap`` holds its
         # estimate — for the default "current" estimator that is exactly
@@ -341,10 +298,7 @@ class ServeEngine:
         self._admit_fn = admission.make_queue_admitter(
             self.policy, self.params,
             batch_mode=cfg.admission_mode == "wavefront",
-            interpret=cfg.kernel_interpret,
-            topk=cfg.wavefront_topk,
-            dedup_buckets=cfg.dedup_buckets,
-            tie_margin=cfg.wavefront_tie_margin)
+            interpret=cfg.kernel_interpret)
         # driver hooks (real-model serving wires prefill/KV surgery here)
         self.on_admit: Optional[Callable[[Request], None]] = None
         self.on_evict: Optional[Callable[[Request], None]] = None

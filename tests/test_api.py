@@ -1,4 +1,4 @@
-"""repro.api: registry round-trip, custom policies, shim equivalence,
+"""repro.api: registry round-trip, custom policies, front-end equivalence,
 Experiment vmapping, and simulator/serving admission parity."""
 import dataclasses
 
@@ -12,9 +12,8 @@ from repro.api import (Experiment, PolicyContext, TaskView, admission,
                        resolve_policy)
 from repro.api.policies import FlexFifoPolicy, PriorityFlexPolicy
 from repro.core import (CLASS_BATCH, CLASS_PRODUCTION, ControllerState,
-                        FlexParams, NodeState, SchedulerKind, SimConfig, run)
-from repro.serving.engine import AdmissionPolicy, EngineConfig, Request, \
-    ServeEngine
+                        FlexParams, NodeState, SimConfig, run)
+from repro.serving.engine import EngineConfig, Request, ServeEngine
 from repro.traces import generate_calibrated
 
 CFG = SimConfig(n_nodes=40, n_slots=12, arrivals_per_slot=128,
@@ -53,24 +52,22 @@ def test_register_custom_factory():
 
 
 def test_resolve_policy_accepts_kind_name_and_object():
+    # a policy has one name, its registry name; objects pass through
     p = get_policy("flex-f")
-    assert resolve_policy(SchedulerKind.FLEX_F) == p
     assert resolve_policy("flex-f") == p
     assert resolve_policy(p) is p
+    with pytest.raises(KeyError, match="registered"):
+        resolve_policy("flex")
 
 
 # ---------------------------------------------------------------------------
-# Shim equivalence: SchedulerKind path == registry/Experiment path
+# Front-end equivalence: the run() entry point == the Experiment path
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("kind,name", [
-    (SchedulerKind.LEAST_FIT, "least-fit"),
-    (SchedulerKind.OVERSUB, "oversub"),
-    (SchedulerKind.FLEX_F, "flex-f"),
-    (SchedulerKind.FLEX_L, "flex-l"),
-])
-def test_shim_bit_identical(ts, kind, name):
-    r_kind = run(ts, CFG, kind)                      # legacy enum entry point
+@pytest.mark.parametrize("name", ["least-fit", "oversub", "flex-f",
+                                  "flex-l"])
+def test_shim_bit_identical(ts, name):
+    r_kind = run(ts, CFG, name)
     r_reg = Experiment(ts, CFG, policy=name).run(seeds=0)
     np.testing.assert_array_equal(np.asarray(r_kind.placement),
                                   np.asarray(r_reg.placement))
@@ -214,7 +211,7 @@ def _parity_case(usage, cap, penalty, declared):
     # serving engine side: replicas as single-resource KV nodes
     eng = ServeEngine(EngineConfig(
         n_replicas=len(usage), kv_budget_tokens=cap,
-        policy=AdmissionPolicy.FLEX, straggler_weight=0.5,
+        policy="flex-f", straggler_weight=0.5,
         admission_mode="sequential", admit_batch=8))
     eng._usage_snap = np.asarray(usage, float)
     eng.ctrl = ControllerState(penalty=jnp.asarray(penalty),

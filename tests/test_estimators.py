@@ -3,8 +3,8 @@
 The contract under test (docs/api.md "Estimators"):
   * built-ins behave (ewma(decay=0) is `current`; noise never goes
     negative; `quantile` matches a numpy sliding-window oracle);
-  * the legacy knobs (`estimator_kind`, `est_noise_std`, stateless
-    estimator objects) resolve BIT-IDENTICALLY to the registry path;
+  * the legacy knobs (`est_noise_std`, stateless estimator objects) and
+    `SimConfig.estimator` resolve BIT-IDENTICALLY to the registry path;
   * the headroom-reclamation pass admits materially more tasks than the
     `current`-estimator baseline at equal-or-lower QoS violation, and
     reuses the wavefront admission path (no second code path);
@@ -135,9 +135,11 @@ def _fingerprint(res):
             np.asarray(res.metrics.qos))
 
 
-def test_estimator_kind_shim_bit_identical(ts):
-    """estimator_kind string == registry name == legacy stateless object."""
-    via_kind = run(ts, CFG, "flex-f", estimator_kind="ewma")
+def test_estimator_resolution_bit_identical(ts):
+    """"ewma" registry name == legacy stateless object ==
+    SimConfig.estimator; an explicit estimator wins over the config's."""
+    via_kind = run(ts, CFG._replace(estimator="current"), "flex-f",
+                   estimator="ewma")
     via_name = run(ts, CFG, "flex-f", estimator="ewma")
     via_obj = run(ts, CFG, "flex-f",
                   estimator=LegacyEwmaEstimator(decay=0.7))
@@ -151,7 +153,7 @@ def test_estimator_kind_shim_bit_identical(ts):
 
 
 def test_noise_shim_bit_identical(ts):
-    via_kind = run(ts, CFG, "flex-f", estimator_kind="current",
+    via_kind = run(ts, CFG, "flex-f", estimator="current",
                    est_noise_std=0.2)
     via_obj = run(ts, CFG, "flex-f",
                   estimator=CurrentEstimator(noise_std=0.2))

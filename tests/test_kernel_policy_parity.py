@@ -4,7 +4,7 @@ The contract (docs/kernels.md): for every policy exposing the
 ``kernel_inputs`` hook, routing admission through the fused Pallas
 filter+score kernel must reproduce the reference ``feasible``/``score``
 path decision-for-decision.  Verified here at three altitudes — one
-``pick_node`` decision, a ``schedule_queue`` scan, and whole simulator
+``pick_node`` decision, an ``admit_queue`` loop, and whole simulator
 runs — with the kernel in interpreter mode so CPU CI runs the real
 tiling/masking logic.
 
@@ -13,9 +13,9 @@ The wavefront tests extend the same contract to batched admission
 conflict-round commits must be placement-for-placement identical to the
 sequential scan, including on an adversarial queue where every task wants
 the same node (one commit per round — the worst case the prefix rule
-must survive, docs/kernels.md).  Parity must hold across the whole knob
-grid: the legacy one-sweep-per-round loop (topk=0), top-K candidate
-caching (topk>0, incl. the K=1 argmax-reduction), and score-bucket dedup
+must survive, docs/kernels.md).  Parity must hold across the whole
+tuning grid of ``admit_queue_wavefront``: top-K candidate caching at
+every K >= 1 (incl. the K=1 argmax-reduction) and score-bucket dedup
 on/off over duplicate-heavy and all-unique queues.
 """
 import jax
@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 
 from repro.api import admission, get_policy, policy_supports_kernel
-from repro.core import SimConfig, run, schedule_queue
+from repro.core import SimConfig, run
 from repro.core.types import FlexParams, NodeState
 from repro.kernels import flex_score
 from repro.traces import generate_calibrated
@@ -83,7 +83,7 @@ def test_pick_node_kernel_matches_reference(name):
 
 
 @pytest.mark.parametrize("name", KERNEL_POLICIES)
-def test_schedule_queue_kernel_matches_reference(name):
+def test_admit_queue_kernel_matches_reference(name):
     pol = get_policy(name)
     params = FlexParams.default()
     node = _node_state(70, jax.random.PRNGKey(2))
@@ -94,11 +94,11 @@ def test_schedule_queue_kernel_matches_reference(name):
     prios = jax.random.randint(ks[2], (Q,), 0, 2)
     valid = jnp.ones((Q,), bool)
     pen = jnp.asarray(1.2)
-    _, pl_ref = schedule_queue(node, reqs, srcs, valid, pen, params, pol,
-                               priorities=prios)
-    _, pl_ker = schedule_queue(node, reqs, srcs, valid, pen, params, pol,
-                               priorities=prios, use_kernel=True,
-                               interpret=True)
+    _, pl_ref = admission.admit_queue(pol, node, reqs, srcs, prios, valid,
+                                      pen, params)
+    _, pl_ker = admission.admit_queue(pol, node, reqs, srcs, prios, valid,
+                                      pen, params, use_kernel=True,
+                                      interpret=True)
     np.testing.assert_array_equal(np.asarray(pl_ref), np.asarray(pl_ker))
 
 
@@ -142,9 +142,10 @@ def _queue(Q, key, n_src=64):
     return reqs, srcs, prios
 
 
-# (topk, dedup_buckets) knob grid: legacy one-sweep-per-round loop,
-# K=1 (argmax-reduction), the K=8 default with and without dedup.
-WAVEFRONT_KNOBS = [(0, 0), (1, 64), (8, 64), (8, 0)]
+# (topk, dedup_buckets) grid of admit_queue_wavefront: a short K=2 list
+# without dedup, K=1 (argmax-reduction), the K=8 default with and without
+# dedup.
+WAVEFRONT_KNOBS = [(2, 0), (1, 64), (8, 64), (8, 0)]
 
 
 @pytest.mark.parametrize("name", KERNEL_POLICIES)
@@ -187,10 +188,30 @@ def test_wavefront_knob_grid_matches_sequential(topk, dedup):
         pen = jnp.asarray(1.2)
         ns_s, pl_s = admission.admit_queue(pol, node, reqs, srcs, prios,
                                            valid, pen, params)
-        ns_w, pl_w = admission.admit_queue(
+        ns_w, pl_w = admission.admit_queue_wavefront(
             pol, node, reqs, srcs, prios, valid, pen, params,
-            batch_mode=True, interpret=True, topk=topk,
-            dedup_buckets=dedup)
+            interpret=True, topk=topk, dedup_buckets=dedup)
+        np.testing.assert_array_equal(np.asarray(pl_s), np.asarray(pl_w))
+        for a, b in zip(ns_s, ns_w):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_wavefront_fat_tie_margin_matches_sequential():
+    # A margin 1000x the default over-flags ties: more rounds and
+    # re-sweeps, never a different decision.
+    pol = get_policy("flex-f")
+    params = FlexParams.default()
+    for seed in range(3):
+        node = _node_state(513, jax.random.PRNGKey(seed))
+        Q = 48
+        reqs, srcs, prios = _queue(Q, jax.random.PRNGKey(seed + 50))
+        valid = jnp.arange(Q) < Q - 4
+        pen = jnp.asarray(1.2)
+        ns_s, pl_s = admission.admit_queue(pol, node, reqs, srcs, prios,
+                                           valid, pen, params)
+        ns_w, pl_w = admission.admit_queue_wavefront(
+            pol, node, reqs, srcs, prios, valid, pen, params,
+            interpret=True, tie_margin=1e-2)
         np.testing.assert_array_equal(np.asarray(pl_s), np.asarray(pl_w))
         for a, b in zip(ns_s, ns_w):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -220,12 +241,12 @@ def test_wavefront_dedup_queue_regimes(dup_heavy):
     pen = jnp.asarray(1.2)
     ns_s, pl_s = admission.admit_queue(pol, node, reqs, srcs, prios, valid,
                                        pen, params)
-    ns_w, pl_w = admission.admit_queue(pol, node, reqs, srcs, prios, valid,
-                                       pen, params, batch_mode=True,
-                                       interpret=True, dedup_buckets=dedup)
-    ns_o, pl_o = admission.admit_queue(pol, node, reqs, srcs, prios, valid,
-                                       pen, params, batch_mode=True,
-                                       interpret=True, dedup_buckets=0)
+    ns_w, pl_w = admission.admit_queue_wavefront(
+        pol, node, reqs, srcs, prios, valid, pen, params, interpret=True,
+        dedup_buckets=dedup)
+    ns_o, pl_o = admission.admit_queue_wavefront(
+        pol, node, reqs, srcs, prios, valid, pen, params, interpret=True,
+        dedup_buckets=0)
     np.testing.assert_array_equal(np.asarray(pl_s), np.asarray(pl_w))
     np.testing.assert_array_equal(np.asarray(pl_s), np.asarray(pl_o))
     for a, b in zip(ns_s, ns_w):
@@ -233,17 +254,18 @@ def test_wavefront_dedup_queue_regimes(dup_heavy):
 
 
 @pytest.mark.parametrize("name", KERNEL_POLICIES)
-@pytest.mark.parametrize("topk", [0, 8])
+@pytest.mark.parametrize("topk", [1, 8])
 def test_wavefront_adversarial_single_hot_node(name, topk):
     # Every task from the same source, one node far emptier than the rest:
     # all pending tasks pick that node, so the dup rule admits one task at
     # a time until the node fills.  This is the degenerate case where the
     # naive "commit unless an earlier task picked the same node" shortcut
     # would still work by accident — but the decisions must match the
-    # sequential scan exactly, commit order included.  With candidate
-    # caching the hot node goes dirty after the first commit and the
-    # dirty-refresh keeps deciding it EXACTLY without re-sweeping, so the
-    # sweep count stays far below the legacy loop's one-per-round.
+    # sequential scan exactly, commit order included.  With K=8 cached
+    # candidates the hot node goes dirty after the first commit and the
+    # dirty-refresh keeps deciding it EXACTLY without re-sweeping; with
+    # K=1 a commit leaves the other tasks no clean candidate, so the head
+    # re-sweeps — at most once per commit.
     pol = get_policy(name)
     params = FlexParams.default()
     n, Q = 33, 24
@@ -265,24 +287,23 @@ def test_wavefront_adversarial_single_hot_node(name, topk):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     placed = int((pl_w >= 0).sum())
     assert placed > 0
-    if topk == 0:
+    if topk == 1:
         # identical tasks => identical candidates => ~one commit per
-        # round, one sweep per round
+        # round; every epoch commits, so sweeps never exceed commits
         assert int(rounds) >= placed
-        assert int(sweeps) == int(rounds)
+        assert int(sweeps) <= placed
     else:
         # candidate fallback: hot-node contention resolves from the cache
         assert int(sweeps) < int(rounds)
         assert int(sweeps) <= placed // 4 + 1
 
 
-@pytest.mark.parametrize("topk,expect_rounds", [(0, 1), (8, 0)])
+@pytest.mark.parametrize("topk,expect_rounds", [(1, 0), (8, 0)])
 def test_wavefront_all_infeasible_finalizes_in_one_sweep(topk,
                                                          expect_rounds):
     # No feasible node for anyone: every task finalizes -1 off the FIRST
-    # sweep (feasibility is antitone in load, docs/kernels.md).  The
-    # legacy loop counts that sweep as its one round; the candidate-cache
-    # loop finalizes at the epoch head and never enters a commit round.
+    # sweep (feasibility is antitone in load, docs/kernels.md), at the
+    # epoch head, for any K: no commit round is ever entered.
     pol = get_policy("flex-f")
     params = FlexParams.default()
     n, Q = 70, 16
@@ -321,26 +342,18 @@ def test_simulator_wavefront_matches_sequential(name, n):
                                np.asarray(wav.metrics.usage))
 
 
-def test_simulator_wavefront_knobs_match_sequential():
-    # The SimConfig knobs (wavefront_topk / dedup_buckets /
-    # wavefront_tie_margin) thread through simulate_core: legacy loop,
-    # dedup-off, and a fat tie margin must all reproduce the sequential
-    # run — the knobs move sweeps/rounds, never placements.
-    cfg = WAVE_CFG._replace(n_nodes=100)
-    ts = generate_calibrated(0, cfg.n_nodes, cfg.n_slots, 1.5)
-    ref = run(ts, cfg, "flex-f")
-    for knobs in (dict(wavefront_topk=0),
-                  dict(wavefront_topk=4, dedup_buckets=0),
-                  dict(wavefront_tie_margin=1e-2)):
-        wav = run(ts, cfg._replace(admission_mode="wavefront",
-                                   kernel_interpret=True, **knobs),
-                  "flex-f")
-        np.testing.assert_array_equal(np.asarray(ref.placement),
-                                      np.asarray(wav.placement))
-        np.testing.assert_array_equal(np.asarray(ref.admit_slot),
-                                      np.asarray(wav.admit_slot))
-        np.testing.assert_array_equal(np.asarray(ref.metrics.n_rejected),
-                                      np.asarray(wav.metrics.n_rejected))
+def test_wavefront_rejects_topk_below_one():
+    # K=0 caches no candidate: there is no loop to run, and asking for one
+    # is an error, not a silent fallback.
+    pol = get_policy("flex-f")
+    node = NodeState.zeros(5)
+    Q = 4
+    zeros = jnp.zeros((Q,), jnp.int32)
+    with pytest.raises(ValueError, match="topk"):
+        admission.admit_queue_wavefront(
+            pol, node, jnp.full((Q, 2), 0.1), zeros, zeros,
+            jnp.ones((Q,), bool), jnp.asarray(1.0), FlexParams.default(),
+            interpret=True, topk=0)
 
 
 def test_wavefront_reference_only_policy_falls_back():

@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels.flex_score.ops import (flex_pick_node,
-                                          flex_pick_node_batch,
                                           flex_pick_node_batch_topk)
 from repro.kernels.flex_score.ref import (pick_node_batch_ref,
                                           pick_node_batch_topk_ref,
@@ -67,6 +66,12 @@ def test_all_infeasible_returns_minus_one(N, tile):
     assert int(i) == -1 and not bool(f)
 
 
+def _batch_argmax(*args, **kw):
+    """The batched argmax: column 0 of the top-1 candidate lists."""
+    i, s, f = flex_pick_node_batch_topk(*args, k=1, **kw)
+    return i[:, 0], s[:, 0], f
+
+
 def _rand_batch(N, Q, scale, seed=7):
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     est = jax.random.uniform(ks[0], (N, 2)) * scale
@@ -85,9 +90,9 @@ def test_batch_matches_batch_ref(N, Q):
     est, res, src, r = _rand_batch(N, Q, 0.8)
     pen = jnp.full((Q,), 1.3)
     ones = jnp.ones((Q,))
-    i_k, _, f_k = flex_pick_node_batch(est, res, src, r, pen, w_load=ones,
-                                       w_src=ones * 0.25, cap=ones,
-                                       tile=128, interpret=True)
+    i_k, _, f_k = _batch_argmax(est, res, src, r, pen, w_load=ones,
+                                w_src=ones * 0.25, cap=ones, tile=128,
+                                interpret=True)
     i_r, _, f_r = pick_node_batch_ref(est, res, src, r, pen, ones,
                                       ones * 0.25, cap=ones)
     assert (jnp.asarray(i_k) == jnp.asarray(i_r)).all()
@@ -102,9 +107,9 @@ def test_batch_rows_match_per_task_kernel(scale):
     N, Q = 513, 9
     est, res, src, r = _rand_batch(N, Q, scale)
     pen = 1.3
-    i_b, s_b, f_b = flex_pick_node_batch(est, res, src, r, pen, w_load=1.0,
-                                         w_src=0.25, cap=1.0, tile=128,
-                                         interpret=True)
+    i_b, s_b, f_b = _batch_argmax(est, res, src, r, pen, w_load=1.0,
+                                  w_src=0.25, cap=1.0, tile=128,
+                                  interpret=True)
     for q in range(Q):
         i_1, s_1, f_1 = flex_pick_node(est, res, src[q], r[q], pen,
                                        tile=128, interpret=True)
@@ -124,9 +129,9 @@ def test_batch_per_task_scalars():
     cap = 0.7 + 0.3 * jax.random.uniform(ks[1], (Q,))
     w_load = jnp.where(jnp.arange(Q) % 2 == 0, 1.0, -1.0)  # incl. best-fit
     w_src = 0.25 * jax.random.uniform(ks[3], (Q,))
-    i_b, _, f_b = flex_pick_node_batch(est, res, src, r, pen, w_load=w_load,
-                                       w_src=w_src, cap=cap, tile=128,
-                                       interpret=True)
+    i_b, _, f_b = _batch_argmax(est, res, src, r, pen, w_load=w_load,
+                                w_src=w_src, cap=cap, tile=128,
+                                interpret=True)
     for q in range(Q):
         i_1, _, f_1 = flex_pick_node(est, res, src[q], r[q], pen[q],
                                      w_load=w_load[q], w_src=w_src[q],
@@ -143,9 +148,9 @@ def test_batch_all_infeasible_rows():
     src = jnp.zeros((Q, N))
     r = jnp.where(jnp.arange(Q)[:, None] % 2 == 0, 0.5,
                   0.005) * jnp.ones((Q, 2))
-    i_b, _, f_b = flex_pick_node_batch(est, jnp.zeros((N, 2)), src, r, 1.0,
-                                       w_load=1.0, w_src=0.25, cap=1.0,
-                                       tile=512, interpret=True)
+    i_b, _, f_b = _batch_argmax(est, jnp.zeros((N, 2)), src, r, 1.0,
+                                w_load=1.0, w_src=0.25, cap=1.0, tile=512,
+                                interpret=True)
     for q in range(Q):
         if q % 2 == 0:
             assert int(i_b[q]) == -1 and not bool(f_b[q])
@@ -182,25 +187,29 @@ def test_topk_matches_topk_ref(N, k):
 
 
 def test_topk_k1_reduces_to_argmax_path():
-    # K=1 must BE the existing batched argmax: same winner, bit-identical
-    # best score (identical float expressions through the same kernel).
+    # K=1 must BE the batched argmax: the batched oracle's winner and
+    # feasibility, and bit-for-bit the best score of a deeper list's
+    # column 0 (identical float expressions through the same kernel).
     for N, tile in [(5, 512), (300, 128), (513, 512), (1024, 256)]:
         Q = 7
         est, res, src, r = _rand_batch(N, Q, 0.8, seed=N)
-        i_1, s_1, f_1 = flex_pick_node_batch(est, res, src, r, 1.3,
-                                             w_load=1.0, w_src=0.25,
-                                             cap=1.0, tile=tile,
-                                             interpret=True)
+        ones = jnp.ones((Q,))
+        i_r, _, f_r = pick_node_batch_ref(est, res, src, r, ones * 1.3, ones,
+                                          ones * 0.25, cap=ones)
         i_t, s_t, f_t = flex_pick_node_batch_topk(est, res, src, r, 1.3,
                                                   w_load=1.0, w_src=0.25,
                                                   cap=1.0, k=1, tile=tile,
                                                   interpret=True)
+        _, s_4, _ = flex_pick_node_batch_topk(est, res, src, r, 1.3,
+                                              w_load=1.0, w_src=0.25,
+                                              cap=1.0, k=4, tile=tile,
+                                              interpret=True)
         assert i_t.shape == (Q, 1)
-        assert (jnp.asarray(i_t[:, 0]) == jnp.asarray(i_1)).all()
-        assert (jnp.asarray(f_t) == jnp.asarray(f_1)).all()
-        feas = jnp.asarray(f_1)
+        assert (jnp.asarray(i_t[:, 0]) == jnp.asarray(i_r)).all()
+        assert (jnp.asarray(f_t) == jnp.asarray(f_r)).all()
+        feas = jnp.asarray(f_r)
         assert (jnp.where(feas, s_t[:, 0], 0.0)
-                == jnp.where(feas, s_1, 0.0)).all()
+                == jnp.where(feas, s_4[:, 0], 0.0)).all()
 
 
 def test_topk_column0_is_argmax_for_any_k():
@@ -209,9 +218,8 @@ def test_topk_column0_is_argmax_for_any_k():
     # wavefront candidate fallback builds on.
     N, Q = 513, 8
     est, res, src, r = _rand_batch(N, Q, 0.8)
-    i_1, _, _ = flex_pick_node_batch(est, res, src, r, 1.3, w_load=1.0,
-                                     w_src=0.25, cap=1.0, tile=128,
-                                     interpret=True)
+    i_1, _, _ = _batch_argmax(est, res, src, r, 1.3, w_load=1.0,
+                              w_src=0.25, cap=1.0, tile=128, interpret=True)
     for k in (2, 8, 16):
         i_t, s_t, _ = flex_pick_node_batch_topk(est, res, src, r, 1.3,
                                                 w_load=1.0, w_src=0.25,

@@ -2,11 +2,26 @@
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import (FlexParams, NodeState, SchedulerKind,
-                        fifo_scheduler, lrf_scheduler, place_task,
-                        schedule_queue)
+from repro.api import PolicyContext, TaskView, admission, get_policy
+from repro.core import (FlexParams, NodeState, fifo_scheduler,
+                        lrf_scheduler)
 
 P = FlexParams.default()
+
+
+def _place(ns, request, penalty, name):
+    """ScheduleOne (Alg. 3) of one valid task from source 0."""
+    ctx = PolicyContext(node=ns, penalty=jnp.asarray(penalty), params=P)
+    task = TaskView(jnp.asarray(request), jnp.asarray(0), jnp.asarray(0))
+    return admission.admit_one(get_policy(name), ctx, task,
+                               jnp.asarray(True))
+
+
+def _admit(ns, reqs, valid, name):
+    """Admit a queue of source-0 batch tasks in queue order at P = 1."""
+    zeros = jnp.zeros((reqs.shape[0],), jnp.int32)
+    return admission.admit_queue(get_policy(name), ns, reqs, zeros, zeros,
+                                 valid, jnp.asarray(1.0), P)
 
 
 def _node(est, requested=None, n=None):
@@ -21,40 +36,30 @@ def _node(est, requested=None, n=None):
 
 def test_flex_places_on_least_loaded():
     ns = _node([[0.8, 0.8], [0.1, 0.1], [0.5, 0.5]])
-    _, idx = place_task(ns, jnp.asarray([0.1, 0.1]), jnp.asarray(0),
-                        jnp.asarray(True), jnp.asarray(1.0), P,
-                        SchedulerKind.FLEX_F)
+    _, idx = _place(ns, [0.1, 0.1], 1.0, "flex-f")
     assert int(idx) == 1
 
 
 def test_flex_respects_capacity_with_penalty():
     ns = _node([[0.6, 0.6]])
     # P=1: 0.6 + 0.3 <= 1 fits;  P=1.5: 0.9 + 0.3 > 1 rejected
-    _, i1 = place_task(ns, jnp.asarray([0.3, 0.3]), jnp.asarray(0),
-                       jnp.asarray(True), jnp.asarray(1.0), P,
-                       SchedulerKind.FLEX_F)
-    _, i2 = place_task(ns, jnp.asarray([0.3, 0.3]), jnp.asarray(0),
-                       jnp.asarray(True), jnp.asarray(1.5), P,
-                       SchedulerKind.FLEX_F)
+    _, i1 = _place(ns, [0.3, 0.3], 1.0, "flex-f")
+    _, i2 = _place(ns, [0.3, 0.3], 1.5, "flex-f")
     assert int(i1) == 0 and int(i2) == -1
 
 
 def test_leastfit_uses_requests_not_usage():
     ns = _node(est=[[0.9, 0.9], [0.0, 0.0]],
                requested=[[0.1, 0.1], [0.8, 0.8]])
-    _, idx = place_task(ns, jnp.asarray([0.1, 0.1]), jnp.asarray(0),
-                        jnp.asarray(True), jnp.asarray(1.0), P,
-                        SchedulerKind.LEAST_FIT)
+    _, idx = _place(ns, [0.1, 0.1], 1.0, "least-fit")
     assert int(idx) == 0  # lowest REQUESTED, despite high usage
 
 
 def test_reservation_accumulates_within_round():
     ns = _node([[0.0, 0.0], [0.0, 0.0]])
     reqs = jnp.full((4, 2), 0.4, jnp.float32)
-    srcs = jnp.zeros((4,), jnp.int32)
     valid = jnp.ones((4,), bool)
-    ns2, placed = schedule_queue(ns, reqs, srcs, valid, jnp.asarray(1.0),
-                                 P, SchedulerKind.FLEX_F)
+    ns2, placed = _admit(ns, reqs, valid, "flex-f")
     placed = np.asarray(placed)
     # 0.4 each, capacity 1.0 -> two per node, alternating via reservations
     assert (placed >= 0).all()
@@ -65,9 +70,7 @@ def test_invalid_entries_skipped():
     ns = _node([[0.0, 0.0]])
     reqs = jnp.full((2, 2), 0.3, jnp.float32)
     valid = jnp.asarray([True, False])
-    ns2, placed = schedule_queue(ns, reqs, jnp.zeros((2,), jnp.int32),
-                                 valid, jnp.asarray(1.0), P,
-                                 SchedulerKind.FLEX_F)
+    ns2, placed = _admit(ns, reqs, valid, "flex-f")
     assert int(placed[0]) == 0 and int(placed[1]) == -1
     assert int(ns2.n_tasks[0]) == 1
 

@@ -1,10 +1,10 @@
-"""Serving engine: Flex admission vs reserve, eviction, stragglers,
+"""Serving engine: Flex admission vs LeastFit, eviction, stragglers,
 eviction/re-queue invariants, and registry policy resolution."""
 import numpy as np
 import pytest
 
-from repro.serving.engine import (AdmissionPolicy, EngineConfig, Request,
-                                  ServeEngine, resolve_engine_policy)
+from repro.api import resolve_policy
+from repro.serving.engine import EngineConfig, Request, ServeEngine
 
 
 def _reqs(n, over=3.0, true=20, prompt=20, seed=0):
@@ -25,7 +25,7 @@ def test_flex_admits_more_than_reserve():
     # flex packs by the real footprints instead of the declared ones and
     # carries far more concurrent work.
     concurrent = {}
-    for pol in (AdmissionPolicy.RESERVE, AdmissionPolicy.FLEX):
+    for pol in ("least-fit", "flex-f"):
         eng = _engine(pol)
         for r in _reqs(64, true=30):
             eng.submit(r)
@@ -34,11 +34,11 @@ def test_flex_admits_more_than_reserve():
             eng.step()
             peak = max(peak, sum(len(v) for v in eng.active.values()))
         concurrent[pol] = peak
-    assert concurrent[AdmissionPolicy.FLEX] > concurrent[AdmissionPolicy.RESERVE]
+    assert concurrent["flex-f"] > concurrent["least-fit"]
 
 
 def test_reserve_never_evicts():
-    eng = _engine(AdmissionPolicy.RESERVE)
+    eng = _engine("least-fit")
     for r in _reqs(64):
         eng.submit(r)
     stats = eng.run(200)
@@ -49,7 +49,7 @@ def test_reserve_never_evicts():
 def test_flex_eviction_and_recovery():
     # adversarial: declared == true (no over-estimation), so usage-based
     # over-admission must overflow, evict, and the penalty must rise
-    eng = _engine(AdmissionPolicy.FLEX)
+    eng = _engine("flex-f")
     for r in _reqs(64, over=1.0, true=60, prompt=40):
         eng.submit(r)
     stats = eng.run(900)
@@ -59,7 +59,7 @@ def test_flex_eviction_and_recovery():
 
 
 def test_straggler_avoidance():
-    eng = _engine(AdmissionPolicy.FLEX)
+    eng = _engine("flex-f")
     eng.step_time_ema = np.asarray([1.0, 10.0])   # replica 1 is slow
     for r in _reqs(8):
         eng.submit(r)
@@ -75,7 +75,7 @@ def _overflow_engine():
     """One replica, honest clients (declared == true): flex over-admits by
     usage and MUST overflow once generation catches up."""
     eng = ServeEngine(EngineConfig(
-        n_replicas=1, kv_budget_tokens=300, policy="flex",
+        n_replicas=1, kv_budget_tokens=300, policy="flex-f",
         max_active_per_replica=16))
     for r in _reqs(12, over=1.0, true=60, prompt=40):
         eng.submit(r)
@@ -162,13 +162,15 @@ def test_no_request_both_done_and_resident():
 # ---------------------------------------------------------------------------
 
 def test_policy_resolves_through_registry():
-    assert resolve_engine_policy("flex").name == "flex-f"
-    assert resolve_engine_policy(AdmissionPolicy.FLEX).name == "flex-f"
-    assert resolve_engine_policy("reserve").name == "least-fit"
-    assert resolve_engine_policy(AdmissionPolicy.RESERVE).name == "least-fit"
-    # any registered policy name is a valid serving policy now
-    assert resolve_engine_policy("flex-priority").name == "flex-priority"
-    assert resolve_engine_policy("best-fit-usage").name == "best-fit-usage"
+    # the engine resolves its policy with the simulator's resolve_policy:
+    # a registry name or an object, "flex-f" by default
+    assert ServeEngine(EngineConfig(n_replicas=2)).policy.name == "flex-f"
+    for name in ("flex-f", "least-fit", "flex-priority", "best-fit-usage"):
+        eng = ServeEngine(EngineConfig(n_replicas=2, policy=name))
+        assert eng.policy == resolve_policy(name)
+        assert eng.policy.name == name
+    pol = resolve_policy("flex-l")
+    assert ServeEngine(EngineConfig(n_replicas=2, policy=pol)).policy is pol
 
 
 def test_unknown_policy_name_errors():

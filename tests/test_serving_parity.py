@@ -30,11 +30,10 @@ import jax.numpy as jnp
 
 from repro.api import admission
 from repro.api.protocols import policy_queue_order
-from repro.serving.engine import (EngineConfig, Request, ServeEngine,
-                                  resolve_engine_policy)
+from repro.serving.engine import EngineConfig, Request, ServeEngine
 from repro.serving.stream import RequestStream, StreamConfig
 
-PARITY_POLICIES = ["flex", "reserve", "flex-priority"]
+PARITY_POLICIES = ["flex-f", "least-fit", "flex-priority"]
 
 # width every reference call pads to: one compiled scan shape per policy
 # for the whole module instead of one per random queue length (XLA's CPU
@@ -151,7 +150,7 @@ def test_engine_matches_admit_queue_randomized(policy):
                 err_msg=f"policy={policy} mode={mode} case={case}")
 
 
-@pytest.mark.parametrize("policy", ["flex", "reserve"])
+@pytest.mark.parametrize("policy", ["flex-f", "least-fit"])
 def test_trajectory_parity_across_modes(policy):
     """Whole open-loop trajectories (admission + eviction event streams,
     final stats) are identical across eager/sequential/wavefront."""

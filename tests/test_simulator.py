@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.api import Experiment
-from repro.core import SchedulerKind, SimConfig, allocation, run
+from repro.core import SimConfig, allocation, run
 from repro.traces import analysis, generate_calibrated
 
 CFG = SimConfig(n_nodes=60, n_slots=32, arrivals_per_slot=256,
@@ -20,8 +20,7 @@ def ts():
 @pytest.fixture(scope="module")
 def results(ts):
     return {k: run(ts, CFG, k) for k in
-            (SchedulerKind.LEAST_FIT, SchedulerKind.OVERSUB,
-             SchedulerKind.FLEX_F, SchedulerKind.FLEX_L)}
+            ("least-fit", "oversub", "flex-f", "flex-l")}
 
 
 def test_node_capacity_never_exceeded(results):
@@ -40,28 +39,28 @@ def test_placements_valid(results, ts):
 
 
 def test_flex_beats_leastfit_utilization(results, ts):
-    s_lf = analysis.summarize(ts, results[SchedulerKind.LEAST_FIT], 0.99)
-    s_ff = analysis.summarize(ts, results[SchedulerKind.FLEX_F], 0.99)
+    s_lf = analysis.summarize(ts, results["least-fit"], 0.99)
+    s_ff = analysis.summarize(ts, results["flex-f"], 0.99)
     assert s_ff["avg_usage_cpu"] > 1.2 * s_lf["avg_usage_cpu"]
     assert s_ff["n_admitted"] > s_lf["n_admitted"]
 
 
 def test_flex_qos_beats_oversub(results):
-    q_flex = float(jnp.mean(results[SchedulerKind.FLEX_F].metrics.qos))
-    q_over = float(jnp.mean(results[SchedulerKind.OVERSUB].metrics.qos))
+    q_flex = float(jnp.mean(results["flex-f"].metrics.qos))
+    q_over = float(jnp.mean(results["oversub"].metrics.qos))
     assert q_flex >= q_over
     assert q_flex >= 0.985
 
 
 def test_penalty_reacts_to_noise(ts):
-    res = run(ts, CFG, SchedulerKind.FLEX_F, est_noise_std=0.6)
+    res = run(ts, CFG, "flex-f", est_noise_std=0.6)
     p = np.asarray(res.metrics.penalty)
     assert p.max() > 1.5  # controller backed off at least once
 
 
 def test_deterministic(ts):
-    r1 = run(ts, CFG, SchedulerKind.FLEX_F, seed=7)
-    r2 = run(ts, CFG, SchedulerKind.FLEX_F, seed=7)
+    r1 = run(ts, CFG, "flex-f", seed=7)
+    r2 = run(ts, CFG, "flex-f", seed=7)
     np.testing.assert_array_equal(np.asarray(r1.placement),
                                   np.asarray(r2.placement))
 

@@ -20,8 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.flex_score.flex_score import (flex_score_batch_tiles,
-                                                 flex_score_batch_topk_tiles,
+from repro.kernels.flex_score.flex_score import (flex_score_batch_topk_tiles,
                                                  flex_score_tiles)
 
 R = 2   # CPU, MEM
@@ -61,15 +60,16 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _kernel_args(kernel, N, Q, sharding):
+def _lowered(kernel, N, Q, sharding):
     f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
                                               sharding=sharding)
     if kernel == "tiles":    # one task: the per-decision scan body
-        return flex_score_tiles, (f32(N, R), f32(N, R), f32(N, 1),
-                                  f32(1, R + 4))
-    fn = {"batch": flex_score_batch_tiles,
-          "topk": flex_score_batch_topk_tiles}[kernel]
-    return fn, (f32(N, R), f32(N, R), f32(Q, N), f32(Q, R + 4))
+        return flex_score_tiles.lower(f32(N, R), f32(N, R), f32(N, 1),
+                                      f32(1, R + 4))
+    # a queue: the batched argmax (k = 1) and the wavefront's top-K lists
+    k = {"batch": 1, "topk": 8}[kernel]
+    return flex_score_batch_topk_tiles.lower(
+        f32(N, R), f32(N, R), f32(Q, N), f32(Q, R + 4), k=k)
 
 
 @pytest.mark.parametrize("N,Q", [(4000, 5120), (8, 256)],
@@ -77,8 +77,7 @@ def _kernel_args(kernel, N, Q, sharding):
 @pytest.mark.parametrize("kernel", ["tiles", "batch", "topk"])
 def test_kernel_compiles_for_v5e(kernel, N, Q, one_chip,
                                  no_persistent_cache):
-    fn, args = _kernel_args(kernel, N, Q, one_chip)
-    compiled = fn.lower(*args).compile()
+    compiled = _lowered(kernel, N, Q, one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -86,6 +85,5 @@ def test_kernel_compiles_for_v5e(kernel, N, Q, one_chip,
 def test_kernel_keeps_its_name_on_v5e(kernel, one_chip, no_persistent_cache):
     """Every entry point's kernel is the instruction ``flex_score_topk``,
     so a device trace names it the same way whichever path runs it."""
-    fn, args = _kernel_args(kernel, 8, 256, one_chip)
-    text = fn.lower(*args).compile().as_text()
+    text = _lowered(kernel, 8, 256, one_chip).compile().as_text()
     assert re.search(r"%flex_score_topk(\.\d+)? = .*tpu_custom_call", text)
